@@ -51,7 +51,8 @@ def parse_mesh(spec: Optional[str]):
             f"mesh {spec} needs {need} devices, only {len(jax.devices())} "
             f"visible; set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{need}")
-    return jax.make_mesh(tuple(sizes), tuple(axes))
+    from repro.launch.mesh import make_mesh
+    return make_mesh(sizes, axes)
 
 
 def run_config(name: str, *, slots: int, requests: int, rate: float,
@@ -60,7 +61,6 @@ def run_config(name: str, *, slots: int, requests: int, rate: float,
                with_plan: bool, mesh=None, max_len: int = 64) -> Dict:
     import jax
     from repro.configs.base import get_config, reduced
-    from repro.distributed import sharding
     from repro.models.model import Model, RunConfig
     from repro.serve import loadgen
     from repro.serve.continuous import ContinuousEngine, Request
@@ -68,7 +68,7 @@ def run_config(name: str, *, slots: int, requests: int, rate: float,
 
     cfg = reduced(get_config(name))
     model = Model(cfg, RunConfig(max_seq=max_len))
-    params = model.init(jax.random.PRNGKey(seed))
+    params = model.init(jax.random.PRNGKey(seed), mesh=mesh)
 
     plan = None
     if with_plan:
@@ -86,33 +86,26 @@ def run_config(name: str, *, slots: int, requests: int, rate: float,
     metrics = ServeMetrics(clock, slots=slots)
     engine = ContinuousEngine(model, params, slots=slots, max_len=max_len,
                               queue_limit=queue_limit, metrics=metrics,
-                              plan=plan)
+                              plan=plan, mesh=mesh)
 
-    def drive():
-        i = 0
-        while i < len(stream) or engine.busy:
-            now = clock.now()
-            while i < len(stream) and stream[i].arrival <= now:
-                r = stream[i]
-                if not engine.submit(Request(r.rid, r.prompt, r.max_new),
-                                     arrival=r.arrival):
-                    break                     # backpressure: head waits
-                i += 1
-            if engine.step() == 0 and i < len(stream):
-                # idle before the next arrival: jump a virtual clock,
-                # yield a wall clock
-                gap = stream[i].arrival - clock.now()
-                if gap > 0:
-                    if clock.kind == "virtual":
-                        clock.advance(gap)
-                    else:
-                        time.sleep(min(gap, 0.01))
-
-    if mesh is not None:
-        with sharding.axis_rules(mesh):
-            drive()
-    else:
-        drive()
+    i = 0
+    while i < len(stream) or engine.busy:
+        now = clock.now()
+        while i < len(stream) and stream[i].arrival <= now:
+            r = stream[i]
+            if not engine.submit(Request(r.rid, r.prompt, r.max_new),
+                                 arrival=r.arrival):
+                break                         # backpressure: head waits
+            i += 1
+        if engine.step() == 0 and i < len(stream):
+            # idle before the next arrival: jump a virtual clock, yield a
+            # wall clock
+            gap = stream[i].arrival - clock.now()
+            if gap > 0:
+                if clock.kind == "virtual":
+                    clock.advance(gap)
+                else:
+                    time.sleep(min(gap, 0.01))
 
     entry = {
         "config": name,
@@ -197,6 +190,8 @@ def main(argv=None) -> int:
         args.prompt_hi = min(args.prompt_hi, 7)
         args.out_hi = min(args.out_hi, 5)
 
+    from repro.device import enable_compile_cache
+    enable_compile_cache()
     mesh = parse_mesh(args.mesh)
     entries: List[Dict] = []
     for name in args.configs.split(","):

@@ -90,7 +90,7 @@ offending equation — raising is total-or-loud, never silently wrong.
 | `reduce_sum` / `reduce_max` over the last axis | carried `reduce` |
 | `lax.scan` of `h = a*h + u` / `jnp.cumsum(axis=0)` | carried `scan` (linear / cumsum) |
 | `transpose` (rank-2) / unit-dim `reshape` `squeeze` `broadcast_in_dim` | `transpose` / shape bookkeeping |
-| `pjit` `custom_jvp_call` `stop_gradient` `convert_element_type` ... | inlined / identity |
+| `jit` `custom_jvp_call` `stop_gradient` `convert_element_type` ... | inlined / identity |
 
 Constants (weights captured by the traced function, literals, anything
 foldable) are folded in numpy while possible and **lazily materialised**

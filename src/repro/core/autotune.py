@@ -117,7 +117,7 @@ def best_schedule(m: int, n: int, k: int,
 
 
 def compile_gemm_autotuned(m: int, n: int, k: int, *, dtype: str = "float32",
-                           interpret: bool = True,
+                           interpret: Optional[bool] = None,
                            machine: MachineModel = TPU_V5E) -> CompiledKernel:
     sched, (tm, tn, tk) = best_schedule(m, n, k, machine=machine)
     return compile_gemm(m, n, k, schedule=sched,

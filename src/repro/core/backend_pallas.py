@@ -36,11 +36,13 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .loop_ir import (EwiseTile, FillTile, Kernel, Loop, LoopKind, MatmulTile,
                       MemSpace, ReduceTile, ScanTile, Stmt, TileRef, ZeroTile,
                       _stmt_refs, _stmt_written_refs)
 from .backend_jax import _EWISE_JNP, _JNP_DTYPE
+from ..device import pallas_interpret
 
 
 class EmitError(NotImplementedError):
@@ -207,7 +209,8 @@ def _analyze(kernel: Kernel) -> _Plan:
                  matmul=matmul)
 
 
-def emit(kernel: Kernel, interpret: bool = True) -> Callable[..., jax.Array]:
+def emit(kernel: Kernel,
+         interpret: Optional[bool] = None) -> Callable[..., jax.Array]:
     """Emit ``f(*hbm_inputs) -> out`` for a scheduled kernel.
 
     Dispatch: the single-nest GEMM classifier (``_analyze``) first — it
@@ -216,10 +219,11 @@ def emit(kernel: Kernel, interpret: bool = True) -> Callable[..., jax.Array]:
     everything else (the serving-kernel graphs: several chained nests
     with carried reductions and scans).
 
-    ``interpret=True`` (default here) runs the kernel body in the pallas
-    interpreter so it is exact on CPU; on real TPU pass ``interpret=False``
-    to lower through Mosaic.
+    ``interpret`` defaults to the platform's choice
+    (:func:`repro.device.pallas_interpret`): the interpreter on the CPU
+    backend, Mosaic elsewhere.
     """
+    interpret = pallas_interpret(interpret)
     try:
         return _emit_gemm(kernel, interpret=interpret)
     except EmitError:
@@ -227,7 +231,7 @@ def emit(kernel: Kernel, interpret: bool = True) -> Callable[..., jax.Array]:
 
 
 def _emit_gemm(kernel: Kernel,
-               interpret: bool = True) -> Callable[..., jax.Array]:
+               interpret: bool) -> Callable[..., jax.Array]:
     """The original single-nest contraction emitter (see module doc)."""
     plan = _analyze(kernel)
     buffers = {b.name: b for b in kernel.params + kernel.scratch}
@@ -356,14 +360,16 @@ def _apply_epilogue(epilogue: Sequence[EwiseTile], acc, ref_of, plan: _Plan):
 # cannot express.  The general emitter maps each top-level statement to
 # its own ``pl.pallas_call``:
 #
-#   * the nest's leading @grid chain becomes the pallas grid; every HBM
-#     buffer the stage touches is passed as a full-array block (constant
-#     index map), and tile addressing happens *inside* the body with
-#     ``pl.dslice`` — grid counters resolve to ``pl.program_id``, inner
-#     @seq/@unrolled/@vector counters to python ints at trace time;
-#   * VREG/VMEM scratch (accumulators, scan carries) become local jnp
-#     values updated functionally — carried state threads through the
-#     trace exactly as the sequential schedule orders it;
+#   * the nest's leading @grid chain becomes the pallas grid (a nest with
+#     none grids its outermost sequential loop, see ``_stage_grid``);
+#   * each HBM buffer gets a block that follows the grid on every dim
+#     that all of the stage's refs index by one grid counter, and spans
+#     the dim otherwise (``_block_spec``); tile addressing inside the
+#     block uses ``pl.ds`` — inner @seq/@unrolled/@vector counters
+#     resolve to python ints at trace time;
+#   * VREG/VMEM scratch (accumulators, scan carries) become VMEM scratch
+#     refs read and written by window — carried state threads through
+#     the grid steps exactly as the sequential schedule orders it;
 #   * stages communicate through a host-level environment: each stage's
 #     written HBM buffers feed the next stage's inputs.
 #
@@ -399,24 +405,69 @@ def _stage_io(stmts: Sequence[Stmt]) -> Tuple[List[str], List[str]]:
     return read, written
 
 
-def _emit_stage(kernel: Kernel, top: Stmt, buffers: Dict[str, "Buffer"],
-                interpret: bool):
-    """Build ``stage(env) -> None`` executing one top-level statement as
-    a pallas_call over the host-level buffer environment."""
-    # 1. peel the leading @grid chain
-    grid_vars: List[str] = []
-    grid: List[int] = []
+def _stage_grid(top: Stmt) -> Tuple[List[Loop], List[Stmt]]:
+    """(grid loops, per-step body) of one top-level statement.
+
+    The leading @grid chain becomes the pallas grid.  A stage with no
+    @grid loop maps its outermost @seq/@unrolled loop onto a grid of one
+    dimension instead: the TPU walks the grid in order and VMEM scratch
+    persists across steps, so the carried state threads exactly as the
+    sequential schedule orders it, and each step holds one slice of the
+    stage's operands in VMEM rather than whole arrays."""
+    loops: List[Loop] = []
     cur = top
     while isinstance(cur, Loop) and cur.kind == LoopKind.GRID:
-        grid_vars.append(cur.var.name)
-        grid.append(cur.var.extent)
+        loops.append(cur)
         if len(cur.body) == 1 and isinstance(cur.body[0], Loop) \
                 and cur.body[0].kind == LoopKind.GRID:
             cur = cur.body[0]
         else:
             break
-    inner: List[Stmt] = list(cur.body) if isinstance(cur, Loop) \
-        and cur.kind == LoopKind.GRID else [cur]
+    if loops:
+        return loops, list(loops[-1].body)
+    if isinstance(top, Loop) and top.kind in (LoopKind.SEQUENTIAL,
+                                              LoopKind.UNROLLED):
+        return [top], list(top.body)
+    return [], [top]
+
+
+def _block_spec(refs: Sequence[TileRef], shape: Tuple[int, ...],
+                grid_vars: Sequence[str]):
+    """Block shape and per-dim grid position (None = whole dim) of one
+    HBM buffer.  A dim is blocked when every ref of the stage indexes it
+    by the same grid counter at the same tile, and the tile keeps the
+    TPU's block tiling rule (last two dims a multiple of (8, 128) or the
+    whole dim); otherwise the block spans the dim."""
+    rank = len(shape)
+    block: List[int] = []
+    pos: List[Optional[int]] = []
+    for d in range(rank):
+        keys = {(r.index[d].coeffs, r.index[d].const, r.tile[d])
+                for r in refs}
+        blocked = None
+        if len(keys) == 1:
+            coeffs, const, t = next(iter(keys))
+            if const == 0 and len(coeffs) == 1 and coeffs[0][1] == 1 \
+                    and coeffs[0][0] in grid_vars and shape[d] % t == 0:
+                align = {rank - 1: 128, rank - 2: 8}.get(d, 1)
+                if t % align == 0 or t == shape[d]:
+                    blocked = (t, list(grid_vars).index(coeffs[0][0]))
+        if blocked is None:
+            block.append(shape[d])
+            pos.append(None)
+        else:
+            block.append(blocked[0])
+            pos.append(blocked[1])
+    return tuple(block), tuple(pos)
+
+
+def _emit_stage(kernel: Kernel, top: Stmt, buffers: Dict[str, "Buffer"],
+                interpret: bool):
+    """Build ``stage(env) -> None`` executing one top-level statement as
+    a pallas_call over the host-level buffer environment."""
+    loops, inner = _stage_grid(top)
+    grid_vars = [lp.var.name for lp in loops]
+    grid = [lp.var.extent for lp in loops]
     for s in inner:
         for n in _walk_stmts([s]):
             if isinstance(n, Loop) and n.kind == LoopKind.GRID:
@@ -435,38 +486,33 @@ def _emit_stage(kernel: Kernel, top: Stmt, buffers: Dict[str, "Buffer"],
         raise EmitError(
             f"{kernel.name}: stage would trace {traced} statements "
             f"(grid-map or tile the schedule first)")
-    scratch = [b for b in kernel.scratch
-               if b.name in {r.buffer.name for s in _walk_stmts([top])
-                             if not isinstance(s, Loop)
-                             for r in _stmt_refs(s)}]
+    leaves = [s for s in _walk_stmts([top]) if not isinstance(s, Loop)]
+    refs_of: Dict[str, List[TileRef]] = {}
+    for s in leaves:
+        for r in _stmt_refs(s):
+            refs_of.setdefault(r.buffer.name, []).append(r)
+    scratch = [b for b in kernel.scratch if b.name in refs_of]
+    blocks = {n: _block_spec(refs_of[n], buffers[n].shape, grid_vars)
+              for n in reads + writes}
 
     def body(*refs):
-        ref_of = dict(zip(reads + writes, refs))
-        local: Dict[str, jax.Array] = {
-            b.name: jnp.zeros(b.shape, _JNP_DTYPE[b.type.dtype])
-            for b in scratch}
+        n_io = len(reads) + len(writes)
+        ref_of = dict(zip(reads + writes, refs[:n_io]))
+        ref_of.update(zip((b.name for b in scratch), refs[n_io:]))
+
+        def window(r: TileRef, env):
+            pos = blocks[r.buffer.name][1] if r.buffer.name in blocks \
+                else (None,) * len(r.tile)
+            return tuple(
+                pl.ds(0 if p is not None else e.evaluate(env) * t, t)
+                for e, t, p in zip(r.index, r.tile, pos))
 
         def read(r: TileRef, env):
-            starts = [e.evaluate(env) * t
-                      for e, t in zip(r.index, r.tile)]
-            if r.buffer.name in local:
-                return jax.lax.dynamic_slice(local[r.buffer.name], starts,
-                                             r.tile)
-            ref = ref_of[r.buffer.name]
-            return ref[tuple(pl.dslice(o, t)
-                             for o, t in zip(starts, r.tile))]
+            return ref_of[r.buffer.name][window(r, env)]
 
         def write(r: TileRef, env, val):
-            starts = [e.evaluate(env) * t
-                      for e, t in zip(r.index, r.tile)]
-            if r.buffer.name in local:
-                local[r.buffer.name] = jax.lax.dynamic_update_slice(
-                    local[r.buffer.name],
-                    val.astype(local[r.buffer.name].dtype), starts)
-                return
             ref = ref_of[r.buffer.name]
-            idx = tuple(pl.dslice(o, t) for o, t in zip(starts, r.tile))
-            ref[idx] = val.astype(ref.dtype)
+            ref[window(r, env)] = val.astype(ref.dtype)
 
         def exec_stmt(s: Stmt, env):
             if isinstance(s, ZeroTile):
@@ -532,18 +578,21 @@ def _emit_stage(kernel: Kernel, top: Stmt, buffers: Dict[str, "Buffer"],
         env0 = {v: pl.program_id(i) for i, v in enumerate(grid_vars)}
         go(inner, env0)
 
-    specs = {n: pl.BlockSpec(buffers[n].shape,
-                             (lambda rank: lambda *g: (0,) * rank)(
-                                 len(buffers[n].shape)))
-             for n in reads + writes}
+    def spec(n):
+        block, pos = blocks[n]
+        return pl.BlockSpec(block, lambda *g: tuple(
+            0 if p is None else g[p] for p in pos))
+
     call = pl.pallas_call(
         body,
         grid=tuple(grid) or (1,),
-        in_specs=[specs[n] for n in reads],
-        out_specs=[specs[n] for n in writes],
+        in_specs=[spec(n) for n in reads],
+        out_specs=[spec(n) for n in writes],
         out_shape=[jax.ShapeDtypeStruct(buffers[n].shape,
                                         _JNP_DTYPE[buffers[n].type.dtype])
                    for n in writes],
+        scratch_shapes=[pltpu.VMEM(b.shape, _JNP_DTYPE[b.type.dtype])
+                        for b in scratch],
         interpret=interpret,
     )
 
@@ -575,8 +624,9 @@ def _traced_stmts(stmts) -> int:
 
 
 def emit_general(kernel: Kernel,
-                 interpret: bool = True) -> Callable[..., jax.Array]:
+                 interpret: Optional[bool] = None) -> Callable[..., jax.Array]:
     """Emit a multi-nest kernel as a chain of per-nest pallas_calls."""
+    interpret = pallas_interpret(interpret)
     kernel.verify()
     if len(kernel.outputs) != 1:
         raise EmitError(f"{kernel.name}: exactly one output supported")

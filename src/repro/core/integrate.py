@@ -30,8 +30,7 @@ from .pipeline import compile_gemm
 def _compiled(m: int, n: int, k: int, schedule: str, backend: str):
     ck = compile_gemm(m, n, k, schedule=schedule,
                       want_jax=(backend != "pallas"),
-                      want_pallas=(backend.startswith("pallas")),
-                      interpret=(backend != "pallas_hw"))
+                      want_pallas=(backend == "pallas"))
     if backend == "xla":
         f = ck.run_jax
         return lambda a, b: f(a, b)[0]
@@ -45,8 +44,8 @@ def gemm_op(m: int, n: int, k: int, schedule: str = "tpu_mxu",
             backend: str = "xla") -> Callable:
     """Differentiable (m,k)x(k,n) GEMM generated by the stagecc pipeline.
 
-    backend: "xla" (runs anywhere) | "pallas" (interpret, exact on CPU) |
-    "pallas_hw" (Mosaic lowering, real TPU only).
+    backend: "xla" (runs anywhere) | "pallas" (Mosaic on an accelerator,
+    the interpreter on the CPU backend).
     """
     fwd_fn = _compiled(m, n, k, schedule, backend)
 
@@ -78,12 +77,11 @@ def sharded_gemm_op(mesh, m: int, n: int, k: int, schedule: str = "tpu_mxu",
     row-sharded over ``lhs_axis`` and the rhs replicated (the canonical
     data-parallel deployment of an accelerator IP block)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     n_shards = mesh.shape[lhs_axis]
     if m % n_shards:
         raise ValueError(f"m={m} not divisible by {lhs_axis}={n_shards}")
     local = gemm_op(m // n_shards, n, k, schedule, backend)
-    return shard_map(local, mesh=mesh,
+    return jax.shard_map(local, mesh=mesh,
                      in_specs=(P(lhs_axis, None), P(None, None)),
-                     out_specs=P(lhs_axis, None), check_rep=False)
+                     out_specs=P(lhs_axis, None), check_vma=False)

@@ -359,8 +359,8 @@ def _emit_jax(k: Kernel):
 
 
 @register_pass("emit-pallas", "backend", "emit pallas_call kernel")
-def _emit_pallas(k: Kernel, interpret: int = 1):
-    return backend_pallas.emit(k, interpret=bool(interpret))
+def _emit_pallas(k: Kernel):
+    return backend_pallas.emit(k)
 
 
 # ---- pipeline parsing ---------------------------------------------------------

@@ -44,7 +44,8 @@ class CompiledKernel:
     hbm_bytes: int
     run_ref: Callable                  # numpy oracle
     run_jax: Optional[Callable]        # jitted XLA
-    run_pallas: Optional[Callable]     # pallas_call (interpret on CPU)
+    run_pallas: Optional[Callable]     # pallas_call (interpreted on CPU)
+    pallas_error: Optional[str] = None  # why the emitter refused, if it did
     machine: MachineModel = TPU_V5E    # the model the reports were priced on
     pass_records: List[PassRecord] = dataclasses.field(default_factory=list)
 
@@ -153,7 +154,7 @@ def compile_traced(fn_or_graph, in_specs: Optional[Sequence[spec]] = None,
                    machine: MachineModel = TPU_V5E,
                    want_jax: bool = True,
                    want_pallas: bool = True,
-                   interpret: bool = True,
+                   interpret: Optional[bool] = None,
                    canonicalize: bool = False,
                    pipeline: Optional[str] = None) -> CompiledKernel:
     """Compile through the full stack; with ``canonicalize=True`` the
@@ -194,26 +195,26 @@ def compile_traced(fn_or_graph, in_specs: Optional[Sequence[spec]] = None,
     res = machine_model.resources(hw, machine)
     run_ref = lambda *xs: backend_ref.run(kernel, xs)
     run_jax = backend_jax.emit_jit(kernel) if want_jax else None
-    run_pal = None
+    run_pal = pallas_error = None
     if want_pallas:
         try:
             run_pal = backend_pallas.emit(kernel, interpret=interpret)
-        except backend_pallas.EmitError:
-            run_pal = None
+        except backend_pallas.EmitError as e:
+            pallas_error = str(e)
     return CompiledKernel(
         name=graph.name, graph=graph, kernel=kernel, hw_module=hw,
         schedule=schedule,
         cycles=cyc, resources=res, flops=machine_model.flops(kernel),
         hbm_bytes=machine_model.hbm_bytes(kernel),
         run_ref=run_ref, run_jax=run_jax, run_pallas=run_pal,
-        machine=machine, pass_records=records)
+        pallas_error=pallas_error, machine=machine, pass_records=records)
 
 
 def compile_gemm(m: int, n: int, k: int, schedule: str = "tpu_mxu",
                  dtype: str = "float32", epilogue: str = "none",
                  tile: Optional[Dict[str, int]] = None,
                  machine: MachineModel = TPU_V5E,
-                 interpret: bool = True,
+                 interpret: Optional[bool] = None,
                  want_jax: bool = True,
                  want_pallas: bool = True,
                  canonicalize: bool = False) -> CompiledKernel:

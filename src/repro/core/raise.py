@@ -46,20 +46,12 @@ import dataclasses
 import re
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import Literal as _JaxLiteral
 
 from .tensor_ir import Graph, TensorType, Value
-
-try:  # jax >= 0.4.x keeps Literal/DropVar in jax.core
-    import jax
-    import jax.numpy as jnp
-    from jax.core import Literal as _JaxLiteral
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover - jax is a baked-in dependency
-    jax = None
-    jnp = None
-    _JaxLiteral = ()
-    _HAVE_JAX = False
 
 
 class RaiseError(ValueError):
@@ -159,8 +151,8 @@ _LOWERABLE_OPS = {"matmul", "bias_add", "reduce_sum", "reduce", "scan",
                   "relu", "gelu", "exp", "neg",
                   "tanh", "sigmoid", "sqrt", "rsqrt", "log1p", "abs"}
 
-_CALL_PRIMS = {"pjit", "closed_call", "core_call", "custom_jvp_call",
-               "custom_vjp_call", "remat", "checkpoint", "remat2"}
+_CALL_PRIMS = {"jit", "closed_call", "call", "custom_jvp_call",
+               "custom_vjp_call", "remat2"}
 _IDENTITY_PRIMS = {"sharding_constraint", "stop_gradient", "copy",
                    "device_put", "convert_element_type"}
 
@@ -962,6 +954,8 @@ class RaisedGraph:
         fn = {"ref": compiled.run_ref, "jax": compiled.run_jax,
               "pallas": compiled.run_pallas}[backend]
         outs = fn(*self.bind(*args))
+        if backend == "pallas":              # one output, not a list
+            outs = [outs]
         return [np.asarray(o).reshape(s)
                 for o, s in zip(outs, self.out_shapes)]
 
@@ -990,8 +984,6 @@ def raise_jaxpr(fn: Callable, *in_specs, name: Optional[str] = None,
     ``while``-loop trip counts ``launch.hlo_analysis`` walks out of the
     optimized HLO text.
     """
-    if not _HAVE_JAX:                            # pragma: no cover
-        raise RuntimeError("raise_jaxpr requires jax")
     avals = [_as_aval(s) for s in in_specs]
     closed = jax.make_jaxpr(fn)(*avals)
     gname = _sanitize(name or getattr(fn, "__name__", "raised"))

@@ -16,13 +16,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
-
-try:
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
 
 
 @dataclasses.dataclass(frozen=True)

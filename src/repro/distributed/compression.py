@@ -17,7 +17,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -49,11 +48,11 @@ def make_compressed_allreduce(mesh: Mesh, axis: str = "data"):
 
     def reduce_tree(tree):
         def one(x):
-            fn = shard_map(
+            fn = jax.shard_map(
                 functools.partial(compressed_psum_mean, axis_name=axis),
                 mesh=mesh, in_specs=P(*(axis,) + (None,) * (x.ndim - 1)),
                 out_specs=P(*(axis,) + (None,) * (x.ndim - 1)),
-                check_rep=False)
+                check_vma=False)
             return fn(x)
         return jax.tree.map(one, tree)
 

@@ -7,9 +7,10 @@ so the (rep x hd) tile feeds the MXU per KV block.
 
 Layout: q (B, KV, rep, hd); k/v (B, KV, Smax, hd) — cache pre-transposed
 to head-major, which is also the HBM-friendly layout for decode (each
-(b, g) stream is contiguous).  ``valid`` (B,) int32.
-Grid = (B, KV, nkv); statistics in VMEM scratch across the kv dimension.
-Validated in interpret mode against the pure-jnp oracle.
+(b, g) stream is contiguous).  ``valid`` (B,) int32 is prefetched into
+SMEM as a scalar operand (a rank-1 VMEM block of one row would break the
+TPU's tiling rule).  Grid = (B, KV, nkv); statistics in VMEM scratch
+across the kv dimension.  Validated against the pure-jnp oracle.
 """
 
 from __future__ import annotations
@@ -20,12 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro.device import pallas_interpret
 
 _NEG = -1e30
 
@@ -46,7 +44,7 @@ def _kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     v = v_ref[0, 0].astype(jnp.float32)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (rep, bk)
 
-    valid = valid_ref[0]
+    valid = valid_ref[pl.program_id(0)]
     kpos = ikv * block_k + jax.lax.iota(jnp.int32, block_k)[None, :]
     s = jnp.where(kpos < valid, s, _NEG)
 
@@ -68,31 +66,37 @@ def _kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      valid: jax.Array, *, block_k: int = 256,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """q: (B, KV, rep, hd); k/v: (B, KV, Smax, hd); valid: (B,) int32.
     Returns (B, KV, rep, hd)."""
     B, KV, rep, hd = q.shape
     Smax = k.shape[2]
-    block_k = min(block_k, Smax)
     if Smax % block_k:
-        raise ValueError(f"Smax={Smax} % block_k={block_k}")
+        block_k = Smax               # one block spans a cache of odd depth
     scale = float(1.0 / np.sqrt(hd))
     grid = (B, KV, Smax // block_k)
-    return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, block_k=block_k),
+    # index maps take the prefetched ``valid`` ref as a trailing argument
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, g, i: (b,)),
-            pl.BlockSpec((1, 1, rep, hd), lambda b, g, i: (b, g, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, g, i: (b, g, i, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, g, i: (b, g, i, 0)),
+            pl.BlockSpec((1, 1, rep, hd), lambda b, g, i, _: (b, g, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, g, i, _: (b, g, i, 0)),
+            pl.BlockSpec((1, 1, block_k, hd),
+                         lambda b, g, i, _: (b, g, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, rep, hd), lambda b, g, i: (b, g, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, rep, hd),
+                               lambda b, g, i, _: (b, g, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((rep, hd), jnp.float32),
+                        pltpu.VMEM((rep, 1), jnp.float32),
+                        pltpu.VMEM((rep, 1), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, scale=scale, block_k=block_k),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, rep, hd), q.dtype),
-        scratch_shapes=[_VMEM((rep, hd), jnp.float32),
-                        _VMEM((rep, 1), jnp.float32),
-                        _VMEM((rep, 1), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(valid.astype(jnp.int32), q, k, v)
 
 

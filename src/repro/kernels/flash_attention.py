@@ -21,12 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU scratch memory spaces; available in interpret mode too
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro.device import pallas_interpret
 
 _NEG_INF = -1e30
 
@@ -78,7 +75,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     scale: float | None = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True) -> jax.Array:
+                    block_k: int = 128, interpret: bool | None = None) -> jax.Array:
     """q: (BH, Sq, D); k, v: (BH, Sk, D) -> (BH, Sq, D)."""
     bh, sq, d = q.shape
     _, sk, _ = k.shape
@@ -105,9 +102,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, iq, ikv: (b, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[
-            _VMEM((block_q, d), jnp.float32),
-            _VMEM((block_q, 1), jnp.float32),
-            _VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(q, k, v)

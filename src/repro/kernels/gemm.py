@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.pipeline import compile_gemm
+from repro.device import pallas_interpret
 
 
 def _pick_tile(dim: int, pref: int = 128) -> int:
@@ -41,14 +42,14 @@ def _build(m: int, n: int, k: int, schedule: str, dtype: str,
 
 
 def pallas_gemm(a: jax.Array, b: jax.Array, *, schedule: str = "tpu_mxu_kgrid",
-                interpret: bool = True, tile: int = 128) -> jax.Array:
+                interpret: bool | None = None, tile: int = 128) -> jax.Array:
     """(m,k) @ (k,n) through the stagecc-emitted pallas kernel."""
     m, kdim = a.shape
     k2, n = b.shape
     assert kdim == k2, (a.shape, b.shape)
     dtype = {"float32": "float32", "bfloat16": "bfloat16"}.get(
         str(a.dtype), "float32")
-    ck = _build(m, n, kdim, schedule, dtype, interpret,
+    ck = _build(m, n, kdim, schedule, dtype, pallas_interpret(interpret),
                 _pick_tile(m, tile), _pick_tile(n, tile), _pick_tile(kdim, tile))
     return ck.run_pallas(a, b)
 

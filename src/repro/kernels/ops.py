@@ -2,8 +2,9 @@
 
 ``backend`` selects the execution path everywhere:
   * "xla"              — pure jnp (runs on any device; the dry-run path)
-  * "pallas"           — pallas kernels in interpret mode (exact on CPU)
-  * "pallas_hw"        — pallas lowered through Mosaic (real TPU)
+  * "pallas"           — pallas kernels, lowered through Mosaic on an
+                         accelerator and interpreted on the CPU backend
+  * "pallas_auto"      — the cost-model-selected generated GEMM
 Models take this as config so the same architecture definition runs in
 smoke tests, dry-runs, and on hardware.
 """
@@ -21,7 +22,7 @@ from .flash_attention import flash_attention
 from .gemm import pallas_gemm
 from .ssd_scan import ssd_chunked, ssd_scan
 
-BACKENDS = ("xla", "pallas", "pallas_hw", "pallas_auto")
+BACKENDS = ("xla", "pallas", "pallas_auto")
 
 
 def matmul(a: jax.Array, b: jax.Array, backend: str = "xla",
@@ -37,8 +38,7 @@ def matmul(a: jax.Array, b: jax.Array, backend: str = "xla",
                                     if str(a.dtype) in ("float32", "bfloat16")
                                     else "float32")
         return ck.run_pallas(a, b)
-    return pallas_gemm(a, b, schedule=schedule,
-                       interpret=(backend != "pallas_hw"))
+    return pallas_gemm(a, b, schedule=schedule)
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -57,8 +57,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kf = k.reshape((-1,) + k.shape[-2:])
     vf = v.reshape((-1,) + v.shape[-2:])
     out = flash_attention(qf, kf, vf, causal=causal, window=window,
-                          scale=scale, block_q=block_q, block_k=block_k,
-                          interpret=(backend != "pallas_hw"))
+                          scale=scale, block_q=block_q, block_k=block_k)
     return out.reshape(lead + out.shape[-2:])
 
 
@@ -69,8 +68,7 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     if backend == "xla":
         fn = functools.partial(ssd_chunked, chunk=chunk)
     else:
-        fn = functools.partial(ssd_scan, chunk=chunk,
-                               interpret=(backend != "pallas_hw"))
+        fn = functools.partial(ssd_scan, chunk=chunk)
     call = (lambda xx, dd, bb, cc: fn(xx, dd, A, bb, cc, D))
     for _ in range(x.ndim - 3):
         call = jax.vmap(call)

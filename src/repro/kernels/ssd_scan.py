@@ -24,12 +24,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from repro.device import pallas_interpret
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
@@ -72,7 +69,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
              C: jax.Array, D: jax.Array | None = None, *, chunk: int = 64,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     """x: (S, H, P), dt: (S, H), A: (H,), B/C: (S, N) -> (S, H, P)."""
     S, H, P = x.shape
     N = B.shape[-1]
@@ -93,8 +90,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         ],
         out_specs=pl.BlockSpec((chunk, 1, P), lambda h, c: (c, h, 0)),
         out_shape=jax.ShapeDtypeStruct((S, H, P), x.dtype),
-        scratch_shapes=[_VMEM((P, N), jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        interpret=pallas_interpret(interpret),
     )(x, dt, A, B, C)
     if D is not None:
         y = y + (D[None, :, None] * x.astype(jnp.float32)).astype(y.dtype)

@@ -1,4 +1,8 @@
 import os
+# The dry run simulates the production meshes on host devices. It pins
+# the CPU backend so that it never takes an attached TPU, and the
+# children that ``--all`` spawns inherit both settings.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the

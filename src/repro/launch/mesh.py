@@ -14,6 +14,15 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: the sharding rules place arrays
+    with ``with_sharding_constraint``, which Explicit axes refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,7 +38,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"visible; the dry-run entrypoint must set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             f"any jax import")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return make_mesh(shape, axes, devices=devs[:need])
 
 
 def make_host_mesh(model: Optional[int] = None):
@@ -37,4 +46,4 @@ def make_host_mesh(model: Optional[int] = None):
     n = len(jax.devices())
     model = model or 1
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

@@ -22,6 +22,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config, reduced
+from repro.device import enable_compile_cache
 from repro.models.model import Model, RunConfig
 from repro.serve.engine import Engine, EngineConfig, throughput_stats
 
@@ -71,6 +72,7 @@ def main():
     ap.add_argument("--rate", type=float, default=4.0)
     ap.add_argument("--queue-limit", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import get_config, reduced
 from repro.data.pipeline import DataConfig, Pipeline
+from repro.device import enable_compile_cache
 from repro.distributed.sharding import axis_rules, sharding_for, tree_shardings
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.model import Model, RunConfig
@@ -49,6 +50,7 @@ def main():
     ap.add_argument("--remat", default="none")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

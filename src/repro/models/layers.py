@@ -14,8 +14,10 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard
+from repro.distributed.sharding import (current_mesh, current_rules,
+                                        pspec_for, shard)
 
 Params = Dict[str, Any]
 
@@ -293,7 +295,7 @@ def apply_attention(p: Params, x: jax.Array, cfg, positions: jax.Array,
         kpos = positions
         valid = None
 
-    if (backend in ("pallas", "pallas_hw") and cache is not None and S == 1
+    if (backend == "pallas" and cache is not None and S == 1
             and window is None):
         # serving fast path: the pallas decode kernel attends the cache
         # with VMEM-resident statistics (kernels/decode_attention.py)
@@ -302,9 +304,18 @@ def apply_attention(p: Params, x: jax.Array, cfg, positions: jax.Array,
         qd = q.reshape(B, KV, rep, hd)
         kd = jnp.swapaxes(k, 1, 2)               # (B, KV, Smax, hd)
         vd = jnp.swapaxes(v, 1, 2)
-        out = decode_attention(qd, kd, vd,
-                               jnp.broadcast_to(jnp.asarray(valid), (B,)),
-                               interpret=(backend != "pallas_hw"))
+        kernel = decode_attention
+        mesh = current_mesh()
+        if mesh is not None:
+            # a Mosaic kernel is not partitioned automatically: run it on
+            # each device's shard of the batch and kv-head axes
+            spec = pspec_for(("batch", "kv_heads"), (B, KV), mesh,
+                             current_rules())
+            vspec = P(*spec[:1])
+            kernel = jax.shard_map(decode_attention, mesh=mesh,
+                                   in_specs=(spec, spec, spec, vspec),
+                                   out_specs=spec, check_vma=False)
+        out = kernel(qd, kd, vd, jnp.broadcast_to(jnp.asarray(valid), (B,)))
         out = out.reshape(B, S, H, hd)
     else:
         out = attention_core(q, k, v, positions,

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, get_config
+from repro.distributed.sharding import tree_shardings
 from repro.models import transformer as T
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
@@ -19,7 +20,7 @@ class RunConfig:
     """Execution configuration orthogonal to the architecture."""
     param_dtype: str = "float32"
     activation_dtype: str = "float32"
-    backend: str = "xla"               # xla | pallas | pallas_hw
+    backend: str = "xla"               # xla | pallas
     remat: str = "none"                # none | full | dots
     max_seq: int = 4096                # position-table / cache upper bound
     cache_dtype: str = "float32"
@@ -36,9 +37,17 @@ class Model:
 
     # ---- params ------------------------------------------------------------
 
-    def init(self, key: jax.Array):
-        return T.init_params(self.cfg, mode="init", key=key,
-                             dtype=self.pdtype, max_seq=self.run.max_seq)
+    def init(self, key: jax.Array, mesh=None):
+        """Random parameters; with ``mesh``, built in place on it under the
+        sharding rules by one jit'd init with ``out_shardings``."""
+        init = lambda k: T.init_params(self.cfg, mode="init", key=k,
+                                       dtype=self.pdtype,
+                                       max_seq=self.run.max_seq)
+        if mesh is None:
+            return init(key)
+        shardings = tree_shardings(self.param_axes(), self.param_shapes(),
+                                   mesh)
+        return jax.jit(init, out_shardings=shardings)(key)
 
     def param_shapes(self):
         return T.init_params(self.cfg, mode="shape", dtype=self.pdtype,

@@ -16,7 +16,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import current_mesh, shard
@@ -126,10 +125,10 @@ def _shardmap_moe(p, h, cfg, act, gated, top_idx, gates, mesh):
     tok_spec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0], None)
     w_spec = P("model", None, None)
     wg = p["w_gate"] if gated else p["w_up"]
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec, w_spec, w_spec, w_spec),
-        out_specs=tok_spec, check_rep=False,
+        out_specs=tok_spec, check_vma=False,
     )(h, top_idx, gates, wg, p["w_up"], p["w_down"])
 
 

@@ -289,14 +289,20 @@ def _init_encoder(cfg: ModelConfig, mode, key, dtype) -> Params:
 # --------------------------------------------------------------------------
 
 
-def _window_arrays(cfg: ModelConfig, plan: StackPlan) -> Tuple[jax.Array, ...]:
+def _window_arrays(cfg: ModelConfig, plan: StackPlan
+                   ) -> Tuple[Optional[jax.Array], ...]:
+    """Per pattern position, the scanned windows of its layers, or None
+    where every one of them is global (so the layer sees ``window=None``
+    and keeps the paths that need a static global window, such as the
+    pallas decode kernel)."""
     windows = cfg.layer_windows()
     out = []
     start = len(plan.prefix)
     period = len(plan.pattern)
     for pos in range(period):
         vals = [windows[start + g * period + pos] for g in range(plan.groups)]
-        out.append(jnp.asarray([GLOBAL_WINDOW if w is None else w
+        out.append(None if all(w is None for w in vals) else
+                   jnp.asarray([GLOBAL_WINDOW if w is None else w
                                 for w in vals], jnp.int32))
     return tuple(out)
 

@@ -34,7 +34,7 @@ class BlockChoice:
     status: str                       # "compiled" | "fallback"
     schedule: Optional[str] = None    # pipeline/schedule label
     cycles: Optional[int] = None      # machine-model cycles of the HwIR
-    pallas: bool = False              # general pallas emitter succeeded
+    pallas: bool = False              # pallas kernel emitted and validated
     reason: str = ""                  # validation note or fallback cause
 
     def row(self) -> Dict:
@@ -100,6 +100,13 @@ def _schedule_candidates(graph):
     return cands
 
 
+def _validate(rg, ck, inputs, backend: str) -> None:
+    want = rg.run_ref(*inputs)
+    got = rg.run_compiled(ck, *inputs, backend=backend)
+    for w, g in zip(want, got):
+        np.testing.assert_allclose(g, w, rtol=_VALIDATE_RTOL, atol=1e-5)
+
+
 def plan_blocks(config_name: str, *, seq: int = 8, seed: int = 0,
                 machine: MachineModel = TPU_V5E,
                 validate: bool = True) -> ServeCompilePlan:
@@ -127,24 +134,29 @@ def plan_blocks(config_name: str, *, seq: int = 8, seed: int = 0,
                 last_err = f"{label}: {str(e).splitlines()[0]}"
                 continue
             note = "not validated"
+            pallas = False
             if validate:
                 try:
-                    want = rg.run_ref(*rep.example_inputs)
-                    got = rg.run_compiled(ck, *rep.example_inputs,
-                                          backend="jax")
-                    for w, g in zip(want, got):
-                        np.testing.assert_allclose(
-                            g, w, rtol=_VALIDATE_RTOL, atol=1e-5)
+                    _validate(rg, ck, rep.example_inputs, "jax")
                     note = (f"validated jax backend vs reference at "
                             f"rtol={_VALIDATE_RTOL}")
                 except Exception as e:
                     last_err = f"{label}: validation failed: " \
                                f"{str(e).splitlines()[0]}"
                     continue
+                if ck.run_pallas is None:
+                    note += f"; no pallas kernel: {ck.pallas_error}"
+                else:
+                    try:
+                        _validate(rg, ck, rep.example_inputs, "pallas")
+                        pallas = True
+                        note += "; pallas validated"
+                    except Exception as e:
+                        note += (f"; pallas failed validation: "
+                                 f"{str(e).splitlines()[0]}")
             choice = BlockChoice(
                 rep.block, "compiled", schedule=label,
-                cycles=int(ck.cycles.total),
-                pallas=ck.run_pallas is not None, reason=note)
+                cycles=int(ck.cycles.total), pallas=pallas, reason=note)
             break
         if choice is None:
             choice = BlockChoice(rep.block, "fallback", reason=last_err)

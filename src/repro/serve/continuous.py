@@ -17,6 +17,10 @@ bit-identical to the serial engine's; greedy decode token streams are
 therefore bit-identical too (differential-tested in
 ``tests/test_continuous_batching.py``).
 
+With a ``mesh``, parameters are expected already placed on it (see
+``Model.init(key, mesh)``), the stacked caches are allocated on it under
+the sharding rules, and every jit'd piece traces under those rules.
+
 Per-slot sampling keys are derived by ``fold_in(base_key, rid)`` so the
 token stream of one request never depends on which slot it landed in or
 on what else is resident — unlike the serial engine's single sequential
@@ -27,14 +31,15 @@ scheduling order.  Greedy decoding is unaffected.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
-import functools
 from typing import Deque, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.distributed.sharding import axis_rules, tree_shardings
 from repro.models.model import Model, mask_padded_vocab
 from repro.serve.metrics import ServeMetrics
 
@@ -70,7 +75,7 @@ class ContinuousEngine:
                  max_len: int = 256, temperature: float = 0.0,
                  seed: int = 0, queue_limit: Optional[int] = None,
                  metrics: Optional[ServeMetrics] = None,
-                 plan=None):
+                 plan=None, mesh=None):
         self.model = model
         self.params = params
         self.slots = int(slots)
@@ -79,6 +84,7 @@ class ContinuousEngine:
         self.queue_limit = queue_limit
         self.metrics = metrics
         self.plan = plan                       # ServeCompilePlan or None
+        self.mesh = mesh
         self.base_key = jax.random.PRNGKey(seed)
 
         self.pending: Deque[Request] = collections.deque()
@@ -88,9 +94,18 @@ class ContinuousEngine:
         self._slot_left = np.zeros(self.slots, np.int64)
         self._slot_len = np.zeros(self.slots, np.int64)
 
-        one = model.cache_init(1, self.max_len)
-        self._stacked = jax.tree.map(
-            lambda l: jnp.zeros((self.slots,) + l.shape, l.dtype), one)
+        shapes = jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct((self.slots,) + l.shape, l.dtype),
+            model.cache_shapes(1, self.max_len))
+        zeros = lambda: jax.tree.map(lambda l: jnp.zeros(l.shape, l.dtype),
+                                     shapes)
+        if mesh is None:
+            self._stacked = zeros()
+        else:
+            axes = jax.tree.map(lambda a: "- " + a,
+                                model.cache_axes(1, self.max_len))
+            self._stacked = jax.jit(zeros, out_shardings=tree_shardings(
+                axes, shapes, mesh))()
         self._tok = jnp.zeros((self.slots, 1), jnp.int32)
         self._keys = jnp.stack([jax.random.fold_in(self.base_key, s)
                                 for s in range(self.slots)])
@@ -101,7 +116,14 @@ class ContinuousEngine:
 
     # ---- jit'd pieces ------------------------------------------------------
 
-    def _prefill(self, params, cache1, tokens1, key):
+    def _rules(self):
+        """The mesh's sharding rules, for tracing and allocation."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return axis_rules(self.mesh)
+
+    def _prefill(self, params, tokens1, key):
+        cache1 = self.model.cache_init(1, self.max_len)
         logits, cache1, _ = self.model.apply(params, tokens1, cache=cache1)
         tok = self._sample(logits[:, -1], key)
         return tok, cache1
@@ -177,11 +199,10 @@ class ContinuousEngine:
         """Prefill the next pending request into free slot ``s``."""
         while self.pending:
             req = self.pending.popleft()
-            cache = self.model.cache_init(1, self.max_len)
             key = jax.random.fold_in(self.base_key, req.rid)
             key, sub = jax.random.split(key)
             tok0, cache = self._prefill_one(
-                self.params, cache, jnp.asarray(req.prompt[None, :]), sub)
+                self.params, jnp.asarray(req.prompt[None, :]), sub)
             if self.metrics:
                 self.metrics.clock.advance(VIRTUAL_PREFILL_COST)
                 self.metrics.on_admit(req.rid, len(req.prompt))
@@ -215,6 +236,10 @@ class ContinuousEngine:
 
     def step(self) -> int:
         """Admissions + one batched decode step; returns tokens emitted."""
+        with self._rules():
+            return self._step()
+
+    def _step(self) -> int:
         for s in range(self.slots):
             if self._slot_req[s] is None:
                 self._admit(s)

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.launch.mesh import make_mesh
 from repro.distributed.compression import (dequantize_int8,
                                            error_feedback_update,
                                            make_compressed_allreduce,
@@ -59,7 +60,7 @@ def test_error_feedback_converges_in_expectation():
 
 def test_compressed_allreduce_mean():
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     reduce_fn = make_compressed_allreduce(mesh, "data")
     x = jnp.arange(n * 4, dtype=jnp.float32).reshape(n, 4)
     out = reduce_fn({"g": x})["g"]

@@ -15,6 +15,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.checkpoint import checkpointer as ck
 from repro.configs.base import get_config, reduced
 from repro.distributed.sharding import axis_rules, tree_shardings
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model, RunConfig
 from repro.optim.optimizer import adamw
 from repro.train.step import init_state, state_axes, state_shapes
@@ -24,7 +25,7 @@ model = Model(cfg, RunConfig(max_seq=32))
 opt = adamw(lambda s: 1e-3)
 
 # train-state built and saved on a (4 data x 2 model) mesh
-mesh_a = jax.make_mesh((4, 2), ('data', 'model'))
+mesh_a = make_mesh((4, 2), ('data', 'model'))
 axes = state_axes(model, opt)
 shapes = state_shapes(model, opt)
 with mesh_a, axis_rules(mesh_a):
@@ -34,7 +35,7 @@ with mesh_a, axis_rules(mesh_a):
 ck.save('{d}', 1, state)
 
 # restore onto a (2 data x 4 model) mesh — the elastic path
-mesh_b = jax.make_mesh((2, 4), ('data', 'model'))
+mesh_b = make_mesh((2, 4), ('data', 'model'))
 with mesh_b, axis_rules(mesh_b):
     sh_b = tree_shardings(axes, shapes, mesh_b)
     restored, extra = ck.restore('{d}', target=state, shardings=sh_b)

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.launch.hlo_analysis import (analyze_hlo_module, collective_bytes,
                                        roofline_terms)
 
@@ -87,7 +88,7 @@ def test_sharded_module_collectives_detected():
     if n < 2:
         pytest.skip("needs >=2 devices")
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     f = jax.jit(lambda a: a.sum(),
                 in_shardings=NamedSharding(mesh, P("data")),
                 out_shardings=NamedSharding(mesh, P()))

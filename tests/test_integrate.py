@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core.integrate import gemm_op, sharded_gemm_op
+from repro.launch.mesh import make_mesh
 
 
 def test_custom_vjp_matches_reference():
@@ -56,7 +57,7 @@ def test_inside_jit_and_training_step():
 
 def test_sharded_gemm_under_mesh():
     n = len(jax.devices())
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    mesh = make_mesh((n, 1), ("data", "model"))
     m = 8 * n
     op = sharded_gemm_op(mesh, m, 8, 8, backend="xla")
     rng = np.random.default_rng(2)
@@ -70,7 +71,7 @@ def test_sharded_gemm_under_mesh():
 
 def test_sharded_gemm_rejects_indivisible():
     n = len(jax.devices())
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    mesh = make_mesh((n, 1), ("data", "model"))
     if n == 1:
         pytest.skip("any m divides 1")
     with pytest.raises(ValueError):

@@ -13,6 +13,7 @@ import pytest
 
 from repro.configs.base import get_config, reduced
 from repro.distributed.sharding import axis_rules
+from repro.launch.mesh import make_mesh
 from repro.models import moe as moe_mod
 from repro.models.layers import attention_core, set_attention_options
 from repro.models.model import Model, RunConfig
@@ -60,7 +61,7 @@ def test_shardmap_moe_matches_gspmd():
     params = model.init(jax.random.PRNGKey(1))
     tokens = jax.random.randint(jax.random.PRNGKey(2), (4, 16), 0,
                                 cfg.vocab_size)
-    mesh = jax.make_mesh((n // 2, 2), ("data", "model"))
+    mesh = make_mesh((n // 2, 2), ("data", "model"))
     moe_mod.set_moe_impl("gspmd")
     with mesh, axis_rules(mesh):
         ref, _, _ = jax.jit(lambda p, t: model.apply(p, t))(params, tokens)
@@ -102,7 +103,8 @@ def test_shardmap_moe_subprocess_multi_device():
         "p = m.init(jax.random.PRNGKey(1));"
         "t = jax.random.randint(jax.random.PRNGKey(2), (4, 16), 0, "
         "cfg.vocab_size);"
-        "mesh = jax.make_mesh((2, 4), ('data', 'model'));"
+        "from repro.launch.mesh import make_mesh;"
+        "mesh = make_mesh((2, 4), ('data', 'model'));"
         "moe_mod.set_moe_impl('gspmd');\n"
         "with mesh, axis_rules(mesh):\n"
         "    a, _, _ = jax.jit(lambda p, t: m.apply(p, t))(p, t)\n"

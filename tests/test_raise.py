@@ -369,8 +369,11 @@ def test_fuzz_roundtrip(seed):
 
     # through the pipeline to the LoopIR reference interpreter; the
     # default tpu_mxu schedule (correctly) refuses to grid a scan's time
-    # axis, so scan-bearing programs take the nested schedule
-    sched = "nested" if rg.scan_lengths else "tpu_mxu"
+    # axis or a carried reduction axis, so such programs take the nested
+    # schedule
+    carried = rg.scan_lengths or any(op.opname == "reduce"
+                                     for op in rg.graph.ops)
+    sched = "nested" if carried else "tpu_mxu"
     ck = rg.compile(tile={"m": 4, "n": 4, "k": 4}, schedule=sched,
                     want_jax=False, want_pallas=False)
     (got,) = rg.run_compiled(ck, x, backend="ref")
@@ -501,7 +504,7 @@ def test_scan_body_neg_and_reshape_views():
         helper = jax.jit(lambda t: t * 2.0)
         def step(h, xs):
             at, ut = xs
-            h = at * h + helper(ut)        # pjit call inlined in the body
+            h = at * h + helper(ut)        # jit call inlined in the body
             return h, h
         return jax.lax.scan(step, jnp.zeros((4,)), (a, u))[1]
 

@@ -8,13 +8,14 @@ from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.data.pipeline import DataConfig, Pipeline
+from repro.launch.mesh import make_mesh
 from repro.distributed.sharding import (axis_rules, pspec_for, shard,
                                         sharding_for, tree_shardings)
 
 
 def _mesh():
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))
 
 
 def test_pspec_basic():
