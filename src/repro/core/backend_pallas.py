@@ -306,6 +306,7 @@ def _emit_gemm(kernel: Kernel,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(out_buf.shape, out_dtype),
         interpret=interpret,
+        name=kernel.name,
     )
 
     def fn(*inputs):
@@ -462,9 +463,10 @@ def _block_spec(refs: Sequence[TileRef], shape: Tuple[int, ...],
 
 
 def _emit_stage(kernel: Kernel, top: Stmt, buffers: Dict[str, "Buffer"],
-                interpret: bool):
+                interpret: bool, name: str):
     """Build ``stage(env) -> None`` executing one top-level statement as
-    a pallas_call over the host-level buffer environment."""
+    a pallas_call named ``name`` over the host-level buffer
+    environment."""
     loops, inner = _stage_grid(top)
     grid_vars = [lp.var.name for lp in loops]
     grid = [lp.var.extent for lp in loops]
@@ -594,6 +596,7 @@ def _emit_stage(kernel: Kernel, top: Stmt, buffers: Dict[str, "Buffer"],
         scratch_shapes=[pltpu.VMEM(b.shape, _JNP_DTYPE[b.type.dtype])
                         for b in scratch],
         interpret=interpret,
+        name=name,
     )
 
     def stage(env: Dict[str, jax.Array]) -> None:
@@ -631,8 +634,11 @@ def emit_general(kernel: Kernel,
     if len(kernel.outputs) != 1:
         raise EmitError(f"{kernel.name}: exactly one output supported")
     buffers = {b.name: b for b in kernel.params + kernel.scratch}
-    stages = [_emit_stage(kernel, top, buffers, interpret)
-              for top in kernel.body]
+    # each nest's kernel name leads with its index: a profile keeps only
+    # the start of a long name, and still tells the nests apart
+    stages = [_emit_stage(kernel, top, buffers, interpret,
+                          f"nest{i}_{kernel.name}")
+              for i, top in enumerate(kernel.body)]
     out_name = kernel.outputs[0].name
     out_names = {b.name for b in kernel.outputs}
     in_params = [b for b in kernel.params if b.name not in out_names]

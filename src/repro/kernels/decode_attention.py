@@ -97,7 +97,37 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, rep, hd), q.dtype),
         interpret=pallas_interpret(interpret),
+        name="decode_attention",
     )(valid.astype(jnp.int32), q, k, v)
+
+
+@jax.custom_batching.custom_vmap
+def decode_attention_vmappable(q: jax.Array, k: jax.Array, v: jax.Array,
+                               valid: jax.Array) -> jax.Array:
+    """``decode_attention`` with its defaults, batched under ``vmap`` by
+    :func:`_vmap_per_element`."""
+    return decode_attention(q, k, v, valid)
+
+
+@decode_attention_vmappable.def_vmap
+def _vmap_per_element(axis_size, in_batched, q, k, v, valid):
+    """One kernel call per element of the mapped axis, in a loop.
+
+    This is what pallas does itself for a kernel whose scalar operand
+    (``valid``) is mapped; here each call is a named ``jit``, so the
+    compiled kernel keeps the name ``decode_attention`` (pallas's own
+    loop body leaves it named ``closed_call``)."""
+    args = (q, k, v, valid)
+
+    def body(i, out):
+        one = [jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+               if b else a for a, b in zip(args, in_batched)]
+        return jax.lax.dynamic_update_index_in_dim(
+            out, decode_attention(*one), i, 0)
+
+    shape = q.shape[1:] if in_batched[0] else q.shape
+    out = jnp.zeros((axis_size,) + shape, q.dtype)
+    return jax.lax.fori_loop(0, axis_size, body, out), True
 
 
 def decode_attention_ref(q, k, v, valid):

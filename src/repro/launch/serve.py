@@ -51,7 +51,8 @@ def _serve_continuous(cfg, model, params, args) -> None:
           f"{snap['tokens']['decode']} tokens, "
           f"{snap['tokens_per_s']:.1f} tok/s, "
           f"ttft p50={snap['ttft']['p50']*1e3:.1f}ms "
-          f"p99={snap['ttft']['p99']*1e3:.1f}ms")
+          f"p99={snap['ttft']['p99']*1e3:.1f}ms "
+          f"prefill_compiles={engine.prefill_compiles}")
     print(json.dumps(snap, indent=2, sort_keys=True))
 
 

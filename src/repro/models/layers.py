@@ -299,12 +299,13 @@ def apply_attention(p: Params, x: jax.Array, cfg, positions: jax.Array,
             and window is None):
         # serving fast path: the pallas decode kernel attends the cache
         # with VMEM-resident statistics (kernels/decode_attention.py)
-        from repro.kernels.decode_attention import decode_attention
+        from repro.kernels.decode_attention import \
+            decode_attention_vmappable
         rep = H // KV
         qd = q.reshape(B, KV, rep, hd)
         kd = jnp.swapaxes(k, 1, 2)               # (B, KV, Smax, hd)
         vd = jnp.swapaxes(v, 1, 2)
-        kernel = decode_attention
+        kernel = decode_attention_vmappable
         mesh = current_mesh()
         if mesh is not None:
             # a Mosaic kernel is not partitioned automatically: run it on
@@ -312,7 +313,7 @@ def apply_attention(p: Params, x: jax.Array, cfg, positions: jax.Array,
             spec = pspec_for(("batch", "kv_heads"), (B, KV), mesh,
                              current_rules())
             vspec = P(*spec[:1])
-            kernel = jax.shard_map(decode_attention, mesh=mesh,
+            kernel = jax.shard_map(decode_attention_vmappable, mesh=mesh,
                                    in_specs=(spec, spec, spec, vspec),
                                    out_specs=spec, check_vma=False)
         out = kernel(qd, kd, vd, jnp.broadcast_to(jnp.asarray(valid), (B,)))

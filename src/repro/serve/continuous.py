@@ -26,6 +26,14 @@ token stream of one request never depends on which slot it landed in or
 on what else is resident — unlike the serial engine's single sequential
 key stream, whose sampled (temperature > 0) outputs depend on
 scheduling order.  Greedy decoding is unaffected.
+
+The host's work is marked on the profiler's clock (``TraceAnnotation``):
+``serve.step`` around a step; in it ``serve.admit`` per admission (with
+``serve.prefill``, or ``serve.prefill.compile`` for the first prompt of
+a length, ``serve.first_token`` for the host's wait on it and
+``serve.write``), ``serve.decode`` (dispatch), ``serve.sync`` (the wait
+for the step's tokens) and ``serve.emit`` (the per-slot bookkeeping and
+hooks).  With the profiler off a span costs under a microsecond.
 """
 
 from __future__ import annotations
@@ -33,11 +41,12 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
-from typing import Deque, Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Set
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.distributed.sharding import axis_rules, tree_shardings
 from repro.models.model import Model, mask_padded_vocab
@@ -93,6 +102,9 @@ class ContinuousEngine:
         self._slot_hist: List[List[int]] = [[] for _ in range(self.slots)]
         self._slot_left = np.zeros(self.slots, np.int64)
         self._slot_len = np.zeros(self.slots, np.int64)
+        # prompt lengths prefilled so far: the first of each compiles
+        self._prefill_lengths: Set[int] = set()
+        self.prefill_compiles = 0
 
         shapes = jax.tree.map(
             lambda l: jax.ShapeDtypeStruct((self.slots,) + l.shape, l.dtype),
@@ -198,31 +210,41 @@ class ContinuousEngine:
     def _admit(self, s: int) -> bool:
         """Prefill the next pending request into free slot ``s``."""
         while self.pending:
-            req = self.pending.popleft()
-            key = jax.random.fold_in(self.base_key, req.rid)
-            key, sub = jax.random.split(key)
-            tok0, cache = self._prefill_one(
-                self.params, jnp.asarray(req.prompt[None, :]), sub)
-            if self.metrics:
-                self.metrics.clock.advance(VIRTUAL_PREFILL_COST)
-                self.metrics.on_admit(req.rid, len(req.prompt))
-                self.metrics.on_token(req.rid)
-            first = int(tok0[0])
-            if req.max_new <= 1:
-                # the prefill already sampled the request's only token —
-                # finish without occupying a slot (max_new=1 regression)
-                self.results[req.rid] = np.asarray([first], np.int32)
+            with TraceAnnotation("serve.admit"):
+                req = self.pending.popleft()
+                key = jax.random.fold_in(self.base_key, req.rid)
+                key, sub = jax.random.split(key)
+                compiles = len(req.prompt) not in self._prefill_lengths
+                if compiles:
+                    self._prefill_lengths.add(len(req.prompt))
+                    self.prefill_compiles += 1
+                with TraceAnnotation("serve.prefill.compile" if compiles
+                                     else "serve.prefill"):
+                    tok0, cache = self._prefill_one(
+                        self.params, jnp.asarray(req.prompt[None, :]), sub)
                 if self.metrics:
-                    self.metrics.on_finish(req.rid)
-                continue
-            self._stacked, self._tok, self._keys = self._write_slot(
-                self._stacked, self._tok, self._keys, cache, tok0, key,
-                jnp.int32(s))
-            self._slot_req[s] = req
-            self._slot_hist[s] = [first]
-            self._slot_left[s] = req.max_new - 1
-            self._slot_len[s] = len(req.prompt)
-            return True
+                    self.metrics.clock.advance(VIRTUAL_PREFILL_COST)
+                    self.metrics.on_admit(req.rid, len(req.prompt))
+                with TraceAnnotation("serve.first_token"):
+                    first = int(tok0[0])
+                if self.metrics:
+                    self.metrics.on_token(req.rid)  # after the host has it
+                if req.max_new <= 1:
+                    # the prefill already sampled the request's only token —
+                    # finish without occupying a slot (max_new=1 regression)
+                    self.results[req.rid] = np.asarray([first], np.int32)
+                    if self.metrics:
+                        self.metrics.on_finish(req.rid)
+                    continue
+                with TraceAnnotation("serve.write"):
+                    self._stacked, self._tok, self._keys = self._write_slot(
+                        self._stacked, self._tok, self._keys, cache, tok0,
+                        key, jnp.int32(s))
+                self._slot_req[s] = req
+                self._slot_hist[s] = [first]
+                self._slot_left[s] = req.max_new - 1
+                self._slot_len[s] = len(req.prompt)
+                return True
         return False
 
     def _finish(self, s: int) -> None:
@@ -236,7 +258,7 @@ class ContinuousEngine:
 
     def step(self) -> int:
         """Admissions + one batched decode step; returns tokens emitted."""
-        with self._rules():
+        with self._rules(), TraceAnnotation("serve.step"):
             return self._step()
 
     def _step(self) -> int:
@@ -248,25 +270,28 @@ class ContinuousEngine:
             self.metrics.on_step(len(self.pending), int(active.sum()))
         if not active.any():
             return 0
-        self._tok, self._stacked, self._keys = self._decode_all(
-            self.params, self._stacked, self._tok, jnp.asarray(active),
-            self._keys)
+        with TraceAnnotation("serve.decode"):
+            self._tok, self._stacked, self._keys = self._decode_all(
+                self.params, self._stacked, self._tok, jnp.asarray(active),
+                self._keys)
         if self.metrics:
             self.metrics.clock.advance(VIRTUAL_STEP_COST)
-        toks = np.asarray(self._tok[:, 0])
+        with TraceAnnotation("serve.sync"):
+            toks = np.asarray(self._tok[:, 0])
         emitted = 0
-        for s in range(self.slots):
-            if self._slot_req[s] is None:
-                continue
-            self._slot_hist[s].append(int(toks[s]))
-            if self.metrics:
-                self.metrics.on_token(self._slot_req[s].rid)
-            emitted += 1
-            self._slot_left[s] -= 1
-            self._slot_len[s] += 1
-            if self._slot_left[s] <= 0 or \
-                    self._slot_len[s] >= self.max_len - 1:
-                self._finish(s)
+        with TraceAnnotation("serve.emit"):
+            for s in range(self.slots):
+                if self._slot_req[s] is None:
+                    continue
+                self._slot_hist[s].append(int(toks[s]))
+                if self.metrics:
+                    self.metrics.on_token(self._slot_req[s].rid)
+                emitted += 1
+                self._slot_left[s] -= 1
+                self._slot_len[s] += 1
+                if self._slot_left[s] <= 0 or \
+                        self._slot_len[s] >= self.max_len - 1:
+                    self._finish(s)
         return emitted
 
     def drain(self, max_steps: Optional[int] = None) -> Dict[int, np.ndarray]:

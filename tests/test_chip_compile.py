@@ -2,7 +2,8 @@
 
 Nothing runs: each test lowers a kernel through Mosaic for one chip of a
 ``v5e:2x2`` topology that is described, not attached, and checks that the
-compiled program holds the Mosaic kernel (``tpu_custom_call``).  This
+compiled program holds the Mosaic kernel (``tpu_custom_call``) under
+its own name, which is what a profile of the chip shows it as.  This
 catches what interpret mode cannot: blocks that break the TPU's tiling
 rule, primitives Mosaic does not lower, and kernels over their VMEM
 limit.  Widths are qwen2_7b's (d_model 3584, d_ff 18944, 28 query / 4 KV
@@ -16,9 +17,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+import repro.kernels.decode_attention as decode_mod
+from repro.configs.base import get_config, reduced
 from repro.core import frontend as fe
 from repro.core.pipeline import compile_gemm, compile_traced
 from repro.kernels.decode_attention import decode_attention
+from repro.models.model import Model, RunConfig
+from repro.serve.continuous import ContinuousEngine
 
 
 @pytest.fixture(scope="module")
@@ -50,13 +55,21 @@ def _compile(fn, shapes, sharding):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _kernel_names(compiled):
+    """The instruction names of the compiled program's Mosaic kernels:
+    ``%decode_attention.7 = ... custom-call(...)`` -> ``decode_attention``."""
+    return [line.split(" = ", 1)[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+            for line in compiled.as_text().splitlines()
+            if "tpu_custom_call" in line]
+
+
 def test_generated_gemm_qwen2_mlp(one_chip):
     ck = compile_gemm(256, 18944, 3584, schedule="tpu_mxu_kgrid",
                       interpret=False)
     assert ck.run_pallas is not None, ck.pallas_error
     c = _compile(ck.run_pallas, [((256, 3584), jnp.float32),
                                  ((3584, 18944), jnp.float32)], one_chip)
-    assert "tpu_custom_call" in c.as_text()
+    assert _kernel_names(c) == ["gemm_256x18944x3584_none"]
 
 
 def test_generated_flash_head128(one_chip):
@@ -67,9 +80,14 @@ def test_generated_flash_head128(one_chip):
                                  ((d, s), jnp.float32),
                                  ((s, d), jnp.float32),
                                  ((s, s), jnp.float32)], one_chip)
-    # one Mosaic kernel per top-level nest of the graph
-    assert c.as_text().count("tpu_custom_call") == \
-        len(ck.run_pallas.stages)
+    # one Mosaic kernel per top-level nest of the graph, named for it;
+    # a profile keeps about 64 characters of ``program:kernel``, and the
+    # names differ within them
+    names = _kernel_names(c)
+    assert sorted(names) == [f"nest{i}_flash_{s}x{s}x{d}"
+                             for i in range(len(ck.run_pallas.stages))]
+    cut = {f"{ck.run_pallas.__name__}:{n}"[:64] for n in names}
+    assert len(cut) == len(names) == 5
 
 
 def test_decode_attention_qwen2_shapes(one_chip):
@@ -80,4 +98,21 @@ def test_decode_attention_qwen2_shapes(one_chip):
                       ((B, KV, smax, hd), jnp.bfloat16),
                       ((B, KV, smax, hd), jnp.bfloat16),
                       ((B,), jnp.int32)], one_chip)
-    assert "tpu_custom_call" in c.as_text()
+    assert _kernel_names(c) == ["decode_attention"]
+
+
+def test_decode_attention_named_in_batched_step(one_chip, monkeypatch):
+    """Inside the serving engine's decode step (a vmap over slots of a
+    scan over layers) the kernel runs once per slot; it keeps its name
+    there too, where pallas's own batching would call it
+    ``closed_call``."""
+    monkeypatch.setattr(decode_mod, "pallas_interpret",
+                        lambda interpret=None: False)      # Mosaic
+    model = Model(reduced(get_config("qwen2_7b")),
+                  RunConfig(backend="pallas", max_seq=96))
+    eng = ContinuousEngine(model, None, slots=3, max_len=96)
+    put = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    args = jax.tree.map(put, (model.param_shapes(), eng._stacked, eng._tok,
+                              jnp.ones(3, bool), eng._keys))
+    c = jax.jit(eng._batched_step).lower(*args).compile()
+    assert _kernel_names(c) == ["decode_attention"]

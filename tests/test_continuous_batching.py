@@ -171,3 +171,72 @@ def test_temperature_sampling_stays_in_vocab(setup):
                        for i in range(3)])
     for rid in got:
         np.testing.assert_array_equal(got[rid], got2[rid])
+
+
+class _OrderLog(ServeMetrics):
+    """Logs each request's admission and tokens beside the host's waits."""
+
+    def __init__(self, log):
+        super().__init__(VirtualClock(), slots=2)
+        self.log = log
+
+    def on_admit(self, rid, prompt_len):
+        self.log.append(("admit", rid))
+        super().on_admit(rid, prompt_len)
+
+    def on_token(self, rid):
+        self.log.append(("token", rid))
+        super().on_token(rid)
+
+
+class _WaitedToken:
+    """A prefill's first token that logs when the host reads it."""
+
+    def __init__(self, tok, rid, log):
+        self.tok, self.rid, self.log = tok, rid, log
+
+    def __getitem__(self, i):
+        self.log.append(("wait", self.rid))
+        return self.tok[i]
+
+
+@pytest.mark.parametrize("max_new", [1, 3])
+def test_first_token_hook_follows_host_wait(setup, max_new):
+    """``on_token`` for a request's first token comes after the host has
+    read it from the prefill, both where the request finishes at once
+    (max_new=1) and where it takes a slot; ``on_admit`` comes before."""
+    cfg, model, params = setup
+    log = []
+    eng = ContinuousEngine(model, params, slots=2, max_len=32,
+                           metrics=_OrderLog(log))
+    prefill, write = eng._prefill_one, eng._write_slot
+    rids = iter(range(2))
+
+    def logged_prefill(*args):
+        tok0, cache = prefill(*args)
+        return _WaitedToken(tok0, next(rids), log), cache
+
+    eng._prefill_one = logged_prefill
+    eng._write_slot = lambda st, tok, keys, cache, tok0, *rest: write(
+        st, tok, keys, cache, tok0.tok, *rest)
+    eng.serve([Request(rid=i, prompt=np.arange(4 + i, dtype=np.int32),
+                       max_new=max_new) for i in range(2)])
+    for rid in range(2):
+        mine = [e for e, r in log if r == rid]
+        assert mine[:3] == ["admit", "wait", "token"], mine
+        assert mine.count("token") == max_new
+
+
+def test_prefill_compiles_counts_new_lengths(setup):
+    """The first prefill of each prompt length counts as a compile; a
+    length seen before does not, in this engine's life."""
+    cfg, model, params = setup
+    eng = ContinuousEngine(model, params, slots=2, max_len=32)
+    assert eng.prefill_compiles == 0
+    eng.serve([Request(rid=i, prompt=np.arange(n, dtype=np.int32),
+                       max_new=2) for i, n in enumerate([4, 5, 4, 5, 6])])
+    assert eng.prefill_compiles == 3
+    eng.serve([Request(rid=10 + i, prompt=np.arange(n, dtype=np.int32),
+                       max_new=2) for i, n in enumerate([6, 4])])
+    assert eng.prefill_compiles == 3
+    assert eng._prefill_one._cache_size() == 3    # what jit compiled

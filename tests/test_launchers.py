@@ -41,3 +41,16 @@ def test_dryrun_list_cli():
     assert r.returncode == 0, r.stderr[-2000:]
     assert r.stdout.count("SKIP") == 7       # the 7 long_500k skips
     assert r.stdout.count("run") >= 33
+
+
+def test_serve_continuous_cli_reports_prefill_compiles():
+    """The operator's summary line counts the prefills that compiled:
+    one per distinct prompt length of the stream."""
+    r = _run(["repro.launch.serve", "--arch", "qwen2-7b", "--reduced",
+              "--continuous", "--slots", "2", "--requests", "6",
+              "--prompt-len", "6", "--gen", "4", "--rate", "50"])
+    assert r.returncode == 0, r.stderr[-2000:]
+    line = next(l for l in r.stdout.splitlines()
+                if l.startswith("[serve] continuous:"))
+    n = int(line.rsplit("prefill_compiles=", 1)[1])
+    assert 1 <= n <= 3                  # prompt lengths are drawn in [4, 6]
