@@ -5,8 +5,9 @@ cache of which only ``valid`` entries are live.  Blocked over the cache
 with online softmax; GQA query groups ride along the sublane dimension
 so the (rep x hd) tile feeds the MXU per KV block.
 
-Layout: q (B, KV, rep, hd); k/v (B, KV, Smax, hd) — cache pre-transposed
-to head-major, which is also the HBM-friendly layout for decode (each
+Layout: q (B, KV, rep, hd); k/v (B, KV, Smax, hd) — head-major, the
+layout the model's attention cache is stored in, so the kernel reads it
+with no transpose; it is also the HBM-friendly layout for decode (each
 (b, g) stream is contiguous).  ``valid`` (B,) int32 is prefetched into
 SMEM as a scalar operand (a rank-1 VMEM block of one row would break the
 TPU's tiling rule).  Grid = (B, KV, nkv); statistics in VMEM scratch
@@ -105,29 +106,28 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def decode_attention_vmappable(q: jax.Array, k: jax.Array, v: jax.Array,
                                valid: jax.Array) -> jax.Array:
     """``decode_attention`` with its defaults, batched under ``vmap`` by
-    :func:`_vmap_per_element`."""
+    :func:`_vmap_fold_batch`."""
     return decode_attention(q, k, v, valid)
 
 
 @decode_attention_vmappable.def_vmap
-def _vmap_per_element(axis_size, in_batched, q, k, v, valid):
-    """One kernel call per element of the mapped axis, in a loop.
+def _vmap_fold_batch(axis_size, in_batched, q, k, v, valid):
+    """One kernel call for the whole mapped axis: it is folded into the
+    kernel's batch axis (``axis_size * B`` rows), with unmapped operands
+    broadcast to it.
 
-    This is what pallas does itself for a kernel whose scalar operand
-    (``valid``) is mapped; here each call is a named ``jit``, so the
-    compiled kernel keeps the name ``decode_attention`` (pallas's own
-    loop body leaves it named ``closed_call``)."""
-    args = (q, k, v, valid)
+    Pallas's own rule would loop over the mapped axis, one call per
+    element on a copy of its slice, since the scalar operand (``valid``)
+    is mapped.  The folded call is still named ``decode_attention``, and
+    nests: a vmap outside this one folds again."""
+    def fold(a, batched):
+        if not batched:
+            a = jnp.broadcast_to(a, (axis_size,) + a.shape)
+        return a.reshape((-1,) + a.shape[2:])
 
-    def body(i, out):
-        one = [jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
-               if b else a for a, b in zip(args, in_batched)]
-        return jax.lax.dynamic_update_index_in_dim(
-            out, decode_attention(*one), i, 0)
-
-    shape = q.shape[1:] if in_batched[0] else q.shape
-    out = jnp.zeros((axis_size,) + shape, q.dtype)
-    return jax.lax.fori_loop(0, axis_size, body, out), True
+    out = decode_attention_vmappable(
+        *(fold(a, b) for a, b in zip((q, k, v, valid), in_batched)))
+    return out.reshape((axis_size, -1) + out.shape[1:]), True
 
 
 def decode_attention_ref(q, k, v, valid):

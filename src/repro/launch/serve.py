@@ -52,7 +52,9 @@ def _serve_continuous(cfg, model, params, args) -> None:
           f"{snap['tokens_per_s']:.1f} tok/s, "
           f"ttft p50={snap['ttft']['p50']*1e3:.1f}ms "
           f"p99={snap['ttft']['p99']*1e3:.1f}ms "
-          f"prefill_compiles={engine.prefill_compiles}")
+          f"prefill_compiles={engine.prefill_compiles} "
+          f"inplace_cache_leaves={engine.inplace_cache_leaves}/"
+          f"{engine.cache_leaves}")
     print(json.dumps(snap, indent=2, sort_keys=True))
 
 

@@ -257,12 +257,17 @@ def apply_attention(p: Params, x: jax.Array, cfg, positions: jax.Array,
                     window=None, cache: Optional[Params] = None,
                     kv_len: Optional[jax.Array] = None,
                     backend: str = "xla",
-                    causal: bool = True) -> Tuple[jax.Array, Optional[Params]]:
+                    causal: bool = True,
+                    layer: Optional[jax.Array] = None
+                    ) -> Tuple[jax.Array, Optional[Params]]:
     """Pre-norm GQA attention block with optional KV cache.
 
     Training/prefill: x is (B, S, d), cache None/fresh. Decode: x is
-    (B, 1, d) and ``cache`` holds (B, Smax, KV, hd) ring buffers with
-    ``kv_len`` tokens valid before this call.
+    (B, 1, d) and ``cache`` holds (B, KV, Smax, hd) buffers, head-major
+    as the decode kernel reads them, with ``kv_len`` tokens valid before
+    this call.  With ``layer``, ``cache`` holds the (L, B, KV, Smax, hd)
+    stacks of a layer scan: the new rows are written at ``layer`` where
+    the stack lies, and the returned cache is the whole stack.
     """
     B, S, d = x.shape
     hd, H, KV = cfg.resolved_head_dim, cfg.num_heads, cfg.num_kv_heads
@@ -283,16 +288,14 @@ def apply_attention(p: Params, x: jax.Array, cfg, positions: jax.Array,
     if cache is not None:
         # insert at kv_len (scalar; same for all batch rows)
         start = kv_len if kv_len is not None else jnp.int32(0)
-        ck = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, start, 0, 0))
-        cv = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, start, 0, 0))
-        new_cache = {"k": ck, "v": cv}
-        k, v = ck, cv
-        kpos = jnp.arange(k.shape[1])[None, :]
+        new_cache = {n: _append_rows(cache[n], rows, start, layer)
+                     for n, rows in (("k", k), ("v", v))}
+        k, v = (new_cache[n] if layer is None else
+                jax.lax.dynamic_index_in_dim(new_cache[n], layer, 0,
+                                             keepdims=False)
+                for n in ("k", "v"))                 # (B, KV, Smax, hd)
         valid = start + S
     else:
-        kpos = positions
         valid = None
 
     if (backend == "pallas" and cache is not None and S == 1
@@ -303,8 +306,6 @@ def apply_attention(p: Params, x: jax.Array, cfg, positions: jax.Array,
             decode_attention_vmappable
         rep = H // KV
         qd = q.reshape(B, KV, rep, hd)
-        kd = jnp.swapaxes(k, 1, 2)               # (B, KV, Smax, hd)
-        vd = jnp.swapaxes(v, 1, 2)
         kernel = decode_attention_vmappable
         mesh = current_mesh()
         if mesh is not None:
@@ -316,15 +317,33 @@ def apply_attention(p: Params, x: jax.Array, cfg, positions: jax.Array,
             kernel = jax.shard_map(decode_attention_vmappable, mesh=mesh,
                                    in_specs=(spec, spec, spec, vspec),
                                    out_specs=spec, check_vma=False)
-        out = kernel(qd, kd, vd, jnp.broadcast_to(jnp.asarray(valid), (B,)))
+        out = kernel(qd, k, v, jnp.broadcast_to(jnp.asarray(valid), (B,)))
         out = out.reshape(B, S, H, hd)
     else:
+        if cache is not None:
+            k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
+            kpos = jnp.arange(k.shape[1])[None, :]
+        else:
+            kpos = positions
         out = attention_core(q, k, v, positions,
                              jnp.broadcast_to(kpos, (B, k.shape[1])),
                              None if valid is None else jnp.asarray(valid),
                              causal=causal, window=window)
     out = jnp.einsum("bshd,hdm->bsm", out, p["wo"].reshape(H, hd, d))
     return x + shard(out, "batch", None, None), new_cache
+
+
+def _append_rows(buf: jax.Array, rows: jax.Array, start,
+                 layer: Optional[jax.Array]) -> jax.Array:
+    """Write ``rows`` (B, S, KV, hd) into the head-major cache ``buf`` at
+    row ``start``: (B, KV, Smax, hd), or at ``layer`` of an
+    (L, B, KV, Smax, hd) stack."""
+    rows = jnp.swapaxes(rows, 1, 2).astype(buf.dtype)
+    zero = jnp.zeros((), jnp.int32)
+    at = (zero, zero, jnp.asarray(start, jnp.int32), zero)
+    if layer is not None:
+        rows, at = rows[None], (jnp.asarray(layer, jnp.int32),) + at
+    return jax.lax.dynamic_update_slice(buf, rows, at)
 
 
 def apply_cross_attention(p: Params, x: jax.Array, cfg,

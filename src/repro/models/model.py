@@ -72,6 +72,10 @@ class Model:
     def cache_axes(self, batch: int, max_len: int):
         return T.cache_spec(self.cfg, batch, max_len, self.cdtype, "axes")
 
+    def cache_leaf_counts(self) -> Tuple[int, int]:
+        """(cache leaves the layer scan updates in place, all of them)."""
+        return T.cache_leaf_counts(self.cfg)
+
     # ---- compute -----------------------------------------------------------
 
     def apply(self, params, tokens, *, extra_embeds=None, cache=None):

@@ -73,8 +73,10 @@ def _init_layer(cfg: ModelConfig, kind: str, mk: Maker) -> Params:
 
 def _apply_layer(kind: str, p: Params, x: jax.Array, cfg: ModelConfig,
                  positions: jax.Array, window, cache, kv_len,
-                 backend: str, enc_kv=None):
-    """Returns (x, new_cache, aux_loss)."""
+                 backend: str, enc_kv=None, layer=None):
+    """Returns (x, new_cache, aux_loss).  ``layer``: the scan index at
+    which the appended K/V stacks in ``cache["attn"]`` are written and
+    read (see :func:`_split_carried`)."""
     cache = cache if cache else None
     aux = jnp.float32(0.0)
     if kind in ("attn", "dense_moe", "moe", "xdec"):
@@ -85,7 +87,8 @@ def _apply_layer(kind: str, p: Params, x: jax.Array, cfg: ModelConfig,
         else:
             x, nc = L.apply_attention(p["attn"], x, cfg, positions,
                                       window=window, cache=attn_cache,
-                                      kv_len=kv_len, backend=backend)
+                                      kv_len=kv_len, backend=backend,
+                                      layer=layer)
         new_cache = {"attn": nc} if nc is not None else None
         if kind == "xdec":
             if enc_kv is not None:           # encoder ran this call (train/prefill)
@@ -113,14 +116,21 @@ def _apply_layer(kind: str, p: Params, x: jax.Array, cfg: ModelConfig,
     raise ValueError(kind)
 
 
+def _appends_kv(cfg: ModelConfig, kind: str) -> bool:
+    """Whether a layer of ``kind`` keeps an attention K/V cache that each
+    call appends rows to (and does not rewrite whole)."""
+    return kind in ("attn", "xdec") or (kind in ("moe", "dense_moe")
+                                        and cfg.mla is None)
+
+
 def _layer_cache_spec(cfg: ModelConfig, kind: str, batch: int, max_len: int,
                       dtype) -> Dict[str, Any]:
     hd, KV = cfg.resolved_head_dim, cfg.num_kv_heads
-    if kind in ("attn", "xdec") or (kind in ("moe", "dense_moe")
-                                    and cfg.mla is None):
+    if _appends_kv(cfg, kind):
+        # head-major, the layout the decode kernel reads
         spec = {"attn": {
-            "k": jax.ShapeDtypeStruct((batch, max_len, KV, hd), dtype),
-            "v": jax.ShapeDtypeStruct((batch, max_len, KV, hd), dtype)}}
+            "k": jax.ShapeDtypeStruct((batch, KV, max_len, hd), dtype),
+            "v": jax.ShapeDtypeStruct((batch, KV, max_len, hd), dtype)}}
         if kind == "xdec":
             e = cfg.encoder
             H = cfg.num_heads
@@ -139,7 +149,7 @@ def _layer_cache_spec(cfg: ModelConfig, kind: str, batch: int, max_len: int,
     raise ValueError(kind)
 
 
-_CACHE_AXES = {"k": "batch kv_seq kv_heads -", "v": "batch kv_seq kv_heads -",
+_CACHE_AXES = {"k": "batch kv_heads kv_seq -", "v": "batch kv_heads kv_seq -",
                "ckv": "batch kv_seq -", "kr": "batch kv_seq -",
                "xk": "batch - heads -", "xv": "batch - heads -",
                "h": "batch ff", "conv": "batch - ff",
@@ -289,6 +299,24 @@ def _init_encoder(cfg: ModelConfig, mode, key, dtype) -> Params:
 # --------------------------------------------------------------------------
 
 
+def _split_carried(cfg: ModelConfig, plan: StackPlan, scan_cache):
+    """Split the scanned groups' caches, per pattern position, into
+    (carried, rest).
+
+    ``carried``: the appended attention K/V stacks ({"k", "v"}, or None
+    where the position has none).  They ride the layer scan's carry:
+    each layer writes its new rows where the stack lies and reads its
+    own layer of it, so a decode step copies no stack in or out.
+    ``rest``: every other leaf (SSD and RG-LRU states, MLA's compressed
+    cache, cross-attention K/V: states a call rewrites whole), sliced
+    per layer as the scan's ``xs`` and stacked back as its ``ys``."""
+    carried = tuple(c["attn"] if c and _appends_kv(cfg, kind) else None
+                    for kind, c in zip(plan.pattern, scan_cache))
+    rest = tuple({n: v for n, v in c.items() if kv is None or n != "attn"}
+                 for c, kv in zip(scan_cache, carried))
+    return carried, rest
+
+
 def _window_arrays(cfg: ModelConfig, plan: StackPlan
                    ) -> Tuple[Optional[jax.Array], ...]:
     """Per pattern position, the scanned windows of its layers, or None
@@ -386,30 +414,39 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array, *,
                             for i in range(len(plan.pattern)))
                       if cache is not None else
                       tuple({} for _ in plan.pattern))
+        carried, scan_cache = _split_carried(cfg, plan, scan_cache)
 
-        def body(x, xs):
-            p_sl, c_sl, w_sl = xs
-            ncs = []
+        def body(carry, xs):
+            x, kv = carry
+            p_sl, c_sl, w_sl, g = xs
+            ncs, kvs = [], []
             aux_g = jnp.float32(0.0)
             for i, kind in enumerate(plan.pattern):
+                c = c_sl[i] if kv[i] is None else {**c_sl[i], "attn": kv[i]}
                 x, nc, aux = _apply_layer(kind, p_sl[i], x, cfg, positions,
-                                          w_sl[i], c_sl[i], kv_len, backend,
-                                          enc_kv_fn)
-                ncs.append(nc if nc is not None else {})
+                                          w_sl[i], c, kv_len, backend,
+                                          enc_kv_fn,
+                                          layer=None if kv[i] is None else g)
+                nc = dict(nc) if nc is not None else {}
+                kvs.append(None if kv[i] is None else nc.pop("attn"))
+                ncs.append(nc)
                 aux_g = aux_g + aux
-            return x, (tuple(ncs), aux_g)
+            return (x, tuple(kvs)), (tuple(ncs), aux_g)
 
         if remat != "none":
             policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                       if remat == "dots" else None)
             body = jax.checkpoint(body, policy=policy,
                                   prevent_cse=False)
-        x, (scan_new_cache, auxs) = jax.lax.scan(
-            body, x, (scan_params, scan_cache, windows))
+        (x, carried), (scan_new_cache, auxs) = jax.lax.scan(
+            body, (x, carried),
+            (scan_params, scan_cache, windows, jnp.arange(plan.groups)))
         aux_total += jnp.sum(auxs)
         if cache is not None:
-            new_cache["scan"] = {f"pos{i}": scan_new_cache[i]
-                                 for i in range(len(plan.pattern))}
+            new_cache["scan"] = {
+                f"pos{i}": (scan_new_cache[i] if kv is None else
+                            {**scan_new_cache[i], "attn": kv})
+                for i, kv in enumerate(carried)}
 
     # ---- suffix layers (unrolled) ----
     for i in plan.suffix:
@@ -437,6 +474,18 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array, *,
 # --------------------------------------------------------------------------
 # cache construction
 # --------------------------------------------------------------------------
+
+
+def cache_leaf_counts(cfg: ModelConfig) -> Tuple[int, int]:
+    """(cache leaves that ride the layer scan's carry in place, all cache
+    leaves but the lengths)."""
+    plan = stack_plan(cfg)
+    spec = cache_spec(cfg, 1, 1)
+    scan = tuple(spec.get("scan", {}).get(f"pos{i}", {})
+                 for i in range(len(plan.pattern)))
+    carried, _ = _split_carried(cfg, plan, scan)
+    per_layer = {n: v for n, v in spec.items() if n not in ("len", "enc_done")}
+    return len(jax.tree.leaves(carried)), len(jax.tree.leaves(per_layer))
 
 
 def cache_spec(cfg: ModelConfig, batch: int, max_len: int,

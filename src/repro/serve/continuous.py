@@ -105,6 +105,10 @@ class ContinuousEngine:
         # prompt lengths prefilled so far: the first of each compiles
         self._prefill_lengths: Set[int] = set()
         self.prefill_compiles = 0
+        # of the cache's per-layer leaves, those the decode step writes in
+        # place as the layer scan's carry (appended attention K/V)
+        self.inplace_cache_leaves, self.cache_leaves = \
+            model.cache_leaf_counts()
 
         shapes = jax.tree.map(
             lambda l: jax.ShapeDtypeStruct((self.slots,) + l.shape, l.dtype),

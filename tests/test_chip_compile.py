@@ -13,6 +13,10 @@ The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time.
 """
 
+import dataclasses
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -103,8 +107,8 @@ def test_decode_attention_qwen2_shapes(one_chip):
 
 def test_decode_attention_named_in_batched_step(one_chip, monkeypatch):
     """Inside the serving engine's decode step (a vmap over slots of a
-    scan over layers) the kernel runs once per slot; it keeps its name
-    there too, where pallas's own batching would call it
+    scan over layers) the kernel runs once per layer for all slots; it
+    keeps its name there too, where pallas's own batching would call it
     ``closed_call``."""
     monkeypatch.setattr(decode_mod, "pallas_interpret",
                         lambda interpret=None: False)      # Mosaic
@@ -116,3 +120,66 @@ def test_decode_attention_named_in_batched_step(one_chip, monkeypatch):
                               jnp.ones(3, bool), eng._keys))
     c = jax.jit(eng._batched_step).lower(*args).compile()
     assert _kernel_names(c) == ["decode_attention"]
+
+
+def _elements(shape: str) -> int:
+    return math.prod(int(n) for n in shape.split(",") if n)
+
+
+def test_batched_step_keeps_cache_in_place(one_chip, monkeypatch):
+    """The decode step at ``qwen2_7b.decode_heavy``'s own shapes (7 layers,
+    32 slots, 4096 rows, bf16) copies no K or V stack: it writes each new
+    row where the stack lies and reads one layer per kernel call.
+
+    Passing the cache through the layer scan as ``xs``/``ys`` under the
+    slot vmap cost four whole-stack copies a step (a relayout at entry, a
+    slice in and a write out per layer, a per-slot slice for the kernel)
+    and 2.3 GB of temporaries."""
+    monkeypatch.setattr(decode_mod, "pallas_interpret",
+                        lambda interpret=None: False)      # Mosaic
+    slots, max_len = 32, 4096
+    cfg = dataclasses.replace(get_config("qwen2_7b"), num_layers=7)
+    model = Model(cfg, RunConfig(param_dtype="bfloat16",
+                                 activation_dtype="bfloat16",
+                                 cache_dtype="bfloat16", backend="pallas",
+                                 max_seq=max_len))
+    eng = ContinuousEngine.__new__(ContinuousEngine)       # nothing allocated
+    eng.model, eng.max_len, eng.temperature, eng.mesh = model, max_len, 0.0, None
+    put = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    stacked = jax.tree.map(
+        lambda l: put(jax.ShapeDtypeStruct((slots,) + l.shape, l.dtype)),
+        model.cache_shapes(1, max_len))
+    args = (jax.tree.map(put, model.param_shapes()), stacked,
+            put(jax.ShapeDtypeStruct((slots, 1), jnp.int32)),
+            put(jax.ShapeDtypeStruct((slots,), bool)),
+            put(jax.ShapeDtypeStruct((slots, 2), jnp.uint32)))
+    c = jax.jit(eng._batched_step, donate_argnums=(1,)).lower(*args).compile()
+
+    assert c.memory_analysis().temp_size_in_bytes <= 300_000_000
+    stack = stacked["scan"]["pos0"]["attn"]["k"]
+    row = slots * cfg.num_kv_heads * cfg.resolved_head_dim  # a row per slot
+    lines = c.as_text().splitlines()
+    shapes = {}                                 # instruction -> output shapes
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ", line)
+        if m:
+            shapes[m[1]] = re.findall(r"\[([\d,]*)\]", m[2])
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (?:\(.*?\)|\S+) ([\w-]+)\((.*)",
+                     line)
+        if not m or not any(_elements(s) == stack.size
+                            for s in shapes[m[1]]):
+            continue
+        op, operands = m[2], re.findall(r"%([\w.-]+)", m[3].split(")")[0])
+        if op == "dynamic-update-slice":        # in place: what it writes
+            assert _elements(shapes[operands[1]][0]) <= row, line[:200]
+        else:                                   # passed along, not copied
+            assert op in ("parameter", "get-tuple-element", "tuple",
+                          "while", "bitcast"), line[:200]
+
+    calls = [l for l in lines if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 1                      # once per layer, in the scan
+    # operands: valid, q, k, v; k holds every slot of one layer
+    operands = re.findall(r"\w+\[([\d,]*)\]", calls[0].split(
+        "operand_layout_constraints=", 1)[1].split("}}", 1)[0])
+    assert operands[2] == f"{slots},{cfg.num_kv_heads},{max_len},128"

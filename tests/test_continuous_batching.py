@@ -30,6 +30,31 @@ def setup_ssm():
     return cfg, model, params
 
 
+def _reduced_setup(arch, backend="xla"):
+    cfg = reduced(get_config(arch))
+    model = Model(cfg, RunConfig(max_seq=64, backend=backend))
+    return cfg, model, model.init(jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def setup_windows():
+    """Local and global attention layers in one scan (gemma3)."""
+    return _reduced_setup("gemma3_4b")
+
+
+@pytest.fixture(scope="module")
+def setup_recurrent():
+    """RG-LRU states (rewritten whole) beside attention K/V (appended)
+    in one scanned group."""
+    return _reduced_setup("recurrentgemma_2b")
+
+
+@pytest.fixture(scope="module")
+def setup_pallas():
+    """The decode kernel (interpreted here), batched over slots."""
+    return _reduced_setup("qwen2_7b", backend="pallas")
+
+
 def _mixed_requests(cfg, n=6, seed=1):
     rng = np.random.default_rng(seed)
     return [Request(rid=i,
@@ -70,7 +95,8 @@ def test_more_requests_than_slots(setup):
         assert len(v) == 3
 
 
-@pytest.mark.parametrize("fixture", ["setup", "setup_ssm"])
+@pytest.mark.parametrize("fixture", ["setup", "setup_ssm", "setup_windows",
+                                     "setup_recurrent", "setup_pallas"])
 def test_batched_bit_identical_to_serial(fixture, request):
     """Acceptance: the vmap-batched decode step emits bit-identical
     greedy token streams to the old per-slot B=1 engine on a mixed
@@ -86,6 +112,17 @@ def test_batched_bit_identical_to_serial(fixture, request):
         np.testing.assert_array_equal(batched[r.rid], serial[r.rid],
                                       err_msg=f"request {r.rid}")
         assert len(batched[r.rid]) == r.max_new
+
+
+@pytest.mark.parametrize("fixture,inplace", [("setup", 2), ("setup_ssm", 0)])
+def test_inplace_cache_leaves(fixture, inplace, request):
+    """Appended attention K/V (two leaves a layer kind) ride the layer
+    scan in place; SSD states, rewritten whole each step, do not."""
+    cfg, model, params = request.getfixturevalue(fixture)
+    eng = ContinuousEngine(model, params, slots=2, max_len=32)
+    assert eng.inplace_cache_leaves == inplace
+    per_layer = {n: c for n, c in eng._stacked.items() if n != "len"}
+    assert eng.cache_leaves == len(jax.tree.leaves(per_layer)) > 0
 
 
 @pytest.mark.parametrize("engine_cls", [ContinuousEngine, SerialSlotEngine])

@@ -1,6 +1,7 @@
 """CLI entrypoint smokes: the train and serve launchers run end to end."""
 
 import os
+import re
 import subprocess
 import sys
 
@@ -52,5 +53,20 @@ def test_serve_continuous_cli_reports_prefill_compiles():
     assert r.returncode == 0, r.stderr[-2000:]
     line = next(l for l in r.stdout.splitlines()
                 if l.startswith("[serve] continuous:"))
-    n = int(line.rsplit("prefill_compiles=", 1)[1])
+    n = int(re.search(r"prefill_compiles=(\d+)", line)[1])
     assert 1 <= n <= 3                  # prompt lengths are drawn in [4, 6]
+    assert "inplace_cache_leaves=2/2" in line    # K and V of its one kind
+
+
+def test_serve_continuous_cli_reports_inplace_cache_leaves():
+    """A state-space model keeps no appended K/V: none of its cache
+    leaves rides the layer scan in place."""
+    r = _run(["repro.launch.serve", "--arch", "mamba2-130m", "--reduced",
+              "--continuous", "--slots", "2", "--requests", "3",
+              "--prompt-len", "6", "--gen", "3", "--rate", "50"])
+    assert r.returncode == 0, r.stderr[-2000:]
+    line = next(l for l in r.stdout.splitlines()
+                if l.startswith("[serve] continuous:"))
+    inplace, total = re.search(r"inplace_cache_leaves=(\d+)/(\d+)",
+                               line).groups()
+    assert int(inplace) == 0 < int(total)
