@@ -23,13 +23,14 @@ readings of the scheduler:
   step, while the device waits for it where requests are queued.
 
 Each reading is None where the trace holds none of the spans it reads.
+``bench/trace.py`` counts the spans (``Reduced.spans``); the per-layer
+metrics ``admit_ms`` and ``host_step_ms`` read them through the two
+functions here.
 """
 
 from __future__ import annotations
 
 import argparse
-import collections
-import glob
 import json
 import os
 import sys
@@ -38,43 +39,17 @@ from typing import Dict, List, Optional
 if __package__ in (None, ""):          # run as a script: ``bench`` importable
     sys.path[0] = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-from bench.trace import SLICE_SPAN  # noqa: E402
-
-PREFIX = "serve."
+from bench.trace import reduce_dir, reduce_file  # noqa: E402
 
 
 def file_spans(path: str) -> Dict[str, List[float]]:
     """``serve.*`` name -> [count, seconds] inside the file's slice."""
-    from jax.profiler import ProfileData
-    host = next(p for p in ProfileData.from_file(path).planes
-                if p.name == "/host:CPU")
-    line = next((ln for ln in host.lines
-                 if any(e.name == SLICE_SPAN for e in ln.events)), None)
-    if line is None:
-        raise ValueError(f"{path}: no {SLICE_SPAN!r} span on the host")
-    events = list(line.events)
-    window = next((e.start_ns, e.end_ns) for e in events
-                  if e.name == SLICE_SPAN)
-    out: Dict[str, List[float]] = collections.defaultdict(lambda: [0, 0.0])
-    for e in events:
-        if e.name.startswith(PREFIX) and window[0] <= e.start_ns < window[1]:
-            out[e.name][0] += 1
-            out[e.name][1] += (e.end_ns - e.start_ns) * 1e-9
-    return dict(out)
+    return dict(reduce_file(path).spans)
 
 
 def dir_spans(log_dir: str) -> Dict[str, List[float]]:
     """Every trace file under ``log_dir`` (one per slice), summed."""
-    files = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
-                             recursive=True))
-    if not files:
-        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
-    out: Dict[str, List[float]] = collections.defaultdict(lambda: [0, 0.0])
-    for f in files:
-        for name, (n, s) in file_spans(f).items():
-            out[name][0] += n
-            out[name][1] += s
-    return dict(out)
+    return dict(reduce_dir(log_dir).spans)
 
 
 def admit_ms(spans) -> Optional[float]:

@@ -184,3 +184,45 @@ def test_weights_are_made_from_the_seed(monkeypatch):
     assert all(np.array_equal(x, y) for x, y in zip(la, lb))
     assert not np.array_equal(la[0], lc[0])
     assert {x.dtype for x in la} == {jnp.dtype(jnp.bfloat16)}
+
+
+def _device_from_host_spans(reduce):
+    """The CPU's trace has no device plane.  Stand in for the programs'
+    device time with the host spans around their dispatch and wait, so
+    that every reader has something to read."""
+    def stand_in(self):
+        red = reduce(self)
+        sp = red.spans
+        step = [sp["serve.decode"][0],
+                sp["serve.decode"][1] + sp["serve.sync"][1]]
+        red.modules["_batched_step"] = list(step)
+        red.kernels["_batched_step"] = list(step)
+        red.modules["_prefill"] = [sp["serve.prefill"][0],
+                                   sp["serve.prefill"][1] +
+                                   sp["serve.first_token"][1]]
+        red.modules["_write"] = list(sp["serve.write"])
+        return red
+    return stand_in
+
+
+@pytest.mark.parametrize("name", SERVING)
+def test_a_traced_run_reads_every_per_layer_metric(name, monkeypatch):
+    """A tiny traced run: the scheduler's metrics read the engine's spans,
+    and each share of a peak or roofline lies in (0, 100]."""
+    from bench.tracer import Tracer
+    monkeypatch.setattr(Tracer, "reduce",
+                        _device_from_host_spans(Tracer.reduce))
+    result, lines = _run(name, monkeypatch, trace=True)
+    assert result["correct"], lines
+    cell = tiny.tiny_cell(name)
+    assert set(result["metrics"]) == {m["name"] for m in cell.per_layer}
+    for m in cell.per_layer:
+        v = result["metrics"][m["name"]]["value"]
+        assert v > 0, m["name"]
+        if m["unit"] == "%":
+            assert v <= 100, m["name"]
+    for q in ("admit_ms", "host_step_ms"):
+        got = [v["value"] for k, v in result["metrics"].items()
+               if cells.quantity(k) == q]
+        assert len(got) == 1 and got[0] > 0, q
+    assert result["device"]["window_s"] > 0

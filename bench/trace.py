@@ -8,8 +8,9 @@ One traced slice of a run is bracketed on the host by a span named
   ``XLA Ops`` line (one event per operation; a Mosaic kernel is an op
   whose text holds ``custom_call_target="tpu_custom_call"``);
 * the host plane (``/host:CPU``): the slice's span, the benchmark's own
-  spans (``bench.*``) and JAX's dispatch events, and the launch and
-  completion events that tie the device's clock to the host's.
+  spans (``bench.*``), the serving engine's (``serve.*``) and JAX's
+  dispatch events, and the launch and completion events that tie the
+  device's clock to the host's.
 
 The device clock runs apart from the host's.  Each program's launch
 precedes its start and its completion callback follows its end, so the
@@ -20,7 +21,9 @@ offset lies between the largest (launch - start) and the smallest
 (the union of its operations, averaged over the chips), per program the
 number of runs and their device time, per program the Mosaic kernels'
 count and device time, the device time of each operation, and the idle
-gaps attributed to what the host's main thread was doing in them.
+gaps attributed to what the host's main thread was doing in them, and
+per ``serve.*`` span name its count and seconds (the spans that start
+inside the slice, on the host line that holds it).
 """
 
 from __future__ import annotations
@@ -34,6 +37,7 @@ import re
 from typing import Dict, List, Tuple
 
 SLICE_SPAN = "bench.traced"
+SERVE_SPANS = "serve."
 KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
 _CONTAINERS = ("while", "call", "conditional")
 
@@ -52,12 +56,16 @@ class Reduced:
         default_factory=lambda: collections.defaultdict(float))
     idle: Dict[str, float] = dataclasses.field(
         default_factory=lambda: collections.defaultdict(float))
+    # engine span name -> [count, host seconds]
+    spans: Dict[str, List[float]] = dataclasses.field(
+        default_factory=lambda: collections.defaultdict(lambda: [0, 0.0]))
 
     def merge(self, other: "Reduced") -> "Reduced":
         self.window_s += other.window_s
         self.busy_s += other.busy_s
         for mine, theirs in ((self.modules, other.modules),
-                             (self.kernels, other.kernels)):
+                             (self.kernels, other.kernels),
+                             (self.spans, other.spans)):
             for k, (n, s) in theirs.items():
                 mine[k][0] += n
                 mine[k][1] += s
@@ -139,6 +147,10 @@ def reduce_file(path: str) -> Reduced:
         if e.name != SLICE_SPAN))
 
     red = Reduced(window_s=(span[1] - span[0]) * 1e-9)
+    for e in main.events:
+        if e.name.startswith(SERVE_SPANS) and span[0] <= e.start_ns < span[1]:
+            red.spans[e.name][0] += 1
+            red.spans[e.name][1] += (e.end_ns - e.start_ns) * 1e-9
     busy_total = 0.0
     for plane in devices:
         lines = {ln.name: ln for ln in plane.lines}
