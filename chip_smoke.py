@@ -13,9 +13,11 @@ One chip runs two phases, each through the entry points a user calls:
   its 28 layers) served through ``ContinuousEngine`` with the pallas
   decode-attention kernel, its logits checked against the XLA backend.
 
-``--four-chips`` runs only the whole 28-layer model on a ``data=1,
-model=4`` mesh, and the 7-layer cut sharded against the same cut alone
-on device 0.
+``--four-chips`` runs only the whole 28-layer model at
+``RunConfig(model_parallel=4)``: the model's own ``data=1, model=4``
+mesh, the path ``launch/serve.py --continuous --model-parallel 4`` and
+the benchmark take; then the 7-layer cut on that layout against the same
+cut alone on device 0.
 
 The script exits non-zero when JAX finds no TPU, and any failed check
 ends the run with a non-zero exit code.  The last line of its output is
@@ -145,12 +147,12 @@ def phase_compiler(gemm=GEMM_MNK, flash=(FLASH_SEQ, FLASH_HEAD),
 # ---------------------------------------------------------------------------
 
 
-def serve_models(cfg, max_len: int):
+def serve_models(cfg, max_len: int, model_parallel: int = 1):
     """The pallas-backend model and the XLA-backend model it is checked
-    against, both bf16."""
+    against, both bf16, over ``model_parallel`` devices."""
     run = RunConfig(param_dtype="bfloat16", activation_dtype="bfloat16",
                     cache_dtype="bfloat16", backend="pallas",
-                    max_seq=max_len)
+                    max_seq=max_len, model_parallel=model_parallel)
     return Model(cfg, run), Model(cfg, dataclasses.replace(run,
                                                            backend="xla"))
 
@@ -163,10 +165,10 @@ def init_alone(model, key):
     return model.init(key, mesh=alone)
 
 
-def first_logits(model, params, prompt, max_len: int, mesh=None):
+def first_logits(model, params, prompt, max_len: int):
     """Prefill logits (P, V) and the first greedy decode step's logits
-    (V,), from one compiled program; also returns that program's text and
-    compile seconds."""
+    (V,), from one compiled program under the model's layout; also
+    returns that program's text and compile seconds."""
     def run(params, tokens):
         cache = model.cache_init(1, max_len)
         lp, cache, _ = model.apply(params, tokens, cache=cache)
@@ -178,6 +180,7 @@ def first_logits(model, params, prompt, max_len: int, mesh=None):
 
     tokens = jnp.asarray(prompt[None, :], jnp.int32)
     t0 = time.perf_counter()
+    mesh = model.mesh
     with axis_rules(mesh) if mesh is not None else contextlib.nullcontext():
         compiled = jax.jit(run).lower(params, tokens).compile()
     compile_s = time.perf_counter() - t0
@@ -193,11 +196,11 @@ def requests(cfg, n: int, prompt, out, seed: int):
     return loadgen.generate_stream(load)
 
 
-def serve(model, params, stream, *, slots: int, max_len: int, mesh=None):
+def serve(model, params, stream, *, slots: int, max_len: int):
     """Serve ``stream`` through ContinuousEngine as ``launch.serve
-    --continuous`` does; returns (rid -> tokens, wall seconds)."""
-    engine = ContinuousEngine(model, params, slots=slots, max_len=max_len,
-                              mesh=mesh)
+    --continuous`` does, on the model's layout; returns (rid -> tokens,
+    wall seconds, the engine's bytes per chip of parameters and cache)."""
+    engine = ContinuousEngine(model, params, slots=slots, max_len=max_len)
     t0 = time.perf_counter()
     for r in stream:
         while not engine.submit(Request(r.rid, r.prompt, r.max_new)):
@@ -208,7 +211,8 @@ def serve(model, params, stream, *, slots: int, max_len: int, mesh=None):
                if len(results.get(r.rid, ())) != r.max_new]
     if missing:
         raise SystemExit(f"FAIL serve: requests {missing} did not complete")
-    return results, wall
+    return results, wall, (engine.param_bytes_per_chip,
+                           engine.cache_bytes_per_chip)
 
 
 def cache_len(prompt, out) -> int:
@@ -241,12 +245,13 @@ def phase_serve(cfg, *, n_requests: int = 8, slots: int = 4,
     check("prefill logits pallas vs xla", rel_err(lp, xp), LOGITS_TOL)
     check("first decode logits pallas vs xla", rel_err(ld, xd), LOGITS_TOL)
 
-    got, wall = serve(model, params, stream, slots=slots, max_len=max_len)
+    got, wall, _ = serve(model, params, stream, slots=slots,
+                         max_len=max_len)
     tokens = sum(len(t) for t in got.values())
     log(f"[serve] pallas engine: requests={len(got)} tokens={tokens} "
         f"wall_s_incl_compile={wall!r}")
-    ref, wall = serve(model_xla, params, stream, slots=slots,
-                      max_len=max_len)
+    ref, wall, _ = serve(model_xla, params, stream, slots=slots,
+                         max_len=max_len)
     log(f"[serve] xla engine: requests={len(ref)} "
         f"wall_s_incl_compile={wall!r}")
     same = sum(int(np.sum(got[r] == ref[r])) for r in got)
@@ -262,36 +267,41 @@ def phase_serve(cfg, *, n_requests: int = 8, slots: int = 4,
 
 def phase_four_chips(cfg, *, cut: int = SERVE_LAYERS, n_requests: int = 4,
                      slots: int = 4, prompt=(32, 128), out=(8, 16),
-                     seed: int = 0) -> None:
-    mesh = make_mesh((1, 4), ("data", "model"))
+                     seed: int = 0, model_parallel: int = 4) -> None:
     max_len = cache_len(prompt, out)
-    model, _ = serve_models(cfg, max_len)
+    model, _ = serve_models(cfg, max_len, model_parallel)
     before = device_bytes()
-    params = model.init(jax.random.PRNGKey(seed), mesh=mesh)
+    params = model.init(jax.random.PRNGKey(seed))
     jax.block_until_ready(params)
     held = [a - b for a, b in zip(device_bytes(), before)]
     total = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
-    log(f"[four] {cfg.name}: layers={cfg.num_layers} param_bytes={total} "
+    log(f"[four] {cfg.name}: layers={cfg.num_layers} "
+        f"model_parallel={model_parallel} param_bytes={total} "
         f"per_device_bytes={held}")
-    # spread: each device holds about a quarter (the replicated norms and
-    # biases are a small part of the weights); the CPU reports no bytes
+    # spread: each device holds about 1/model_parallel (the replicated
+    # norms and biases are a small part of the weights); the CPU reports
+    # no bytes
+    share = 1.0 / model_parallel
     if jax.devices()[0].memory_stats() is not None and \
-            not all(0.2 * total < h < 0.35 * total for h in held):
+            not all(0.8 * share * total < h < 1.4 * share * total
+                    for h in held[:model_parallel]):
         raise SystemExit(f"FAIL four: weights not spread: {held}")
     stream = requests(cfg, n_requests, prompt, out, seed)
-    got, wall = serve(model, params, stream, slots=slots, max_len=max_len,
-                      mesh=mesh)
+    got, wall, (pbytes, cbytes) = serve(model, params, stream, slots=slots,
+                                        max_len=max_len)
     log(f"[four] sharded engine: requests={len(got)} "
         f"tokens={sum(len(t) for t in got.values())} "
+        f"param_bytes_per_chip={pbytes} cache_bytes_per_chip={cbytes} "
         f"wall_s_incl_compile={wall!r}")
     del params
 
     cut_cfg = dataclasses.replace(cfg, num_layers=cut)
-    model, _ = serve_models(cut_cfg, max_len)
+    model, _ = serve_models(cut_cfg, max_len, model_parallel)
+    alone, _ = serve_models(cut_cfg, max_len)
     key = jax.random.PRNGKey(seed)
-    sharded = first_logits(model, model.init(key, mesh=mesh),
-                           stream[0].prompt, max_len, mesh=mesh)
-    single = first_logits(model, init_alone(model, key), stream[0].prompt,
+    sharded = first_logits(model, model.init(key), stream[0].prompt,
+                           max_len)
+    single = first_logits(alone, init_alone(alone, key), stream[0].prompt,
                           max_len)
     check(f"{cut}-layer prefill logits sharded vs device 0",
           rel_err(sharded[0], single[0]), LOGITS_TOL)

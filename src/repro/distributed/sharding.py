@@ -23,6 +23,7 @@ Logical axes used across the framework:
 from __future__ import annotations
 
 import contextlib
+import math
 import threading
 from typing import Dict, Optional, Sequence, Tuple, Union
 
@@ -136,3 +137,10 @@ def tree_shardings(axes_tree, shape_tree, mesh: Mesh,
         lambda ax, sds: sharding_for(ax, sds.shape, mesh, rules),
         axes_tree, shape_tree,
         is_leaf=lambda x: isinstance(x, str))
+
+
+def per_device_bytes(tree) -> int:
+    """Bytes of ``tree``'s arrays that one device holds: each leaf's shard
+    (the whole leaf where it is replicated), from its sharding alone."""
+    return sum(math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+               for a in jax.tree.leaves(tree))

@@ -11,6 +11,11 @@ metrics snapshot:
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-7b --reduced \
       --continuous --slots 4 --requests 16 --rate 4
+
+``--model-parallel N`` serves one replica over N devices, its layers
+split tensor-parallel on a ``(data=1, model=N)`` mesh
+(``RunConfig.model_parallel``); the summary line then gives the layout
+and the bytes of parameters and cache that each device holds.
 """
 
 from __future__ import annotations
@@ -54,7 +59,10 @@ def _serve_continuous(cfg, model, params, args) -> None:
           f"p99={snap['ttft']['p99']*1e3:.1f}ms "
           f"prefill_compiles={engine.prefill_compiles} "
           f"inplace_cache_leaves={engine.inplace_cache_leaves}/"
-          f"{engine.cache_leaves}")
+          f"{engine.cache_leaves} "
+          f"layout=data1xmodel{model.run.model_parallel} "
+          f"param_bytes_per_chip={engine.param_bytes_per_chip} "
+          f"cache_bytes_per_chip={engine.cache_bytes_per_chip}")
     print(json.dumps(snap, indent=2, sort_keys=True))
 
 
@@ -74,6 +82,9 @@ def main():
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--rate", type=float, default=4.0)
     ap.add_argument("--queue-limit", type=int, default=None)
+    ap.add_argument("--model-parallel", type=int, default=1,
+                    help="devices one replica spans, tensor-parallel "
+                         "(data=1, model=N)")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -81,7 +92,8 @@ def main():
     if args.reduced:
         cfg = reduced(cfg)
     max_len = args.prompt_len + args.gen + 1
-    model = Model(cfg, RunConfig(max_seq=max_len))
+    model = Model(cfg, RunConfig(max_seq=max_len,
+                                 model_parallel=args.model_parallel))
     params = model.init(jax.random.PRNGKey(args.seed))
     print(f"[serve] arch={cfg.name} params={model.param_count():,}")
 

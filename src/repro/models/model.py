@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, get_config
 from repro.distributed.sharding import tree_shardings
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
@@ -24,25 +25,48 @@ class RunConfig:
     remat: str = "none"                # none | full | dots
     max_seq: int = 4096                # position-table / cache upper bound
     cache_dtype: str = "float32"
+    model_parallel: int = 1            # chips one replica's layers span
+    prefill_chunk: int = 0             # serving: prompt tokens a step
+                                       # prefills while slots decode
+                                       # (0: whole prompts)
+
+
+def _layout_mesh(model_parallel: int):
+    """The ``(data=1, model=n)`` mesh over the first n devices, or None
+    for one device."""
+    if model_parallel <= 1:
+        return None
+    devices = jax.devices()
+    if len(devices) < model_parallel:
+        raise ValueError(f"model_parallel={model_parallel} needs as many "
+                         f"devices; JAX found {len(devices)}")
+    return make_mesh((1, model_parallel), ("data", "model"),
+                     devices=devices[:model_parallel])
 
 
 class Model:
-    """Thin, stateless wrapper tying a ModelConfig to the generic stack."""
+    """Thin, stateless wrapper tying a ModelConfig to the generic stack.
+
+    ``mesh`` is the run's layout: a ``(data=1, model=n)`` mesh where
+    ``run.model_parallel`` is n > 1, else None (one device)."""
 
     def __init__(self, cfg: ModelConfig, run: RunConfig = RunConfig()):
         self.cfg = cfg
         self.run = run
         self.pdtype = DTYPES[run.param_dtype]
         self.cdtype = DTYPES[run.cache_dtype]
+        self.mesh = _layout_mesh(run.model_parallel)
 
     # ---- params ------------------------------------------------------------
 
     def init(self, key: jax.Array, mesh=None):
-        """Random parameters; with ``mesh``, built in place on it under the
-        sharding rules by one jit'd init with ``out_shardings``."""
+        """Random parameters; with a mesh (``mesh``, else the model's
+        own), built in place on it under the sharding rules by one jit'd
+        init with ``out_shardings``."""
         init = lambda k: T.init_params(self.cfg, mode="init", key=k,
                                        dtype=self.pdtype,
                                        max_seq=self.run.max_seq)
+        mesh = self.mesh if mesh is None else mesh
         if mesh is None:
             return init(key)
         shardings = tree_shardings(self.param_axes(), self.param_shapes(),

@@ -17,9 +17,18 @@ bit-identical to the serial engine's; greedy decode token streams are
 therefore bit-identical too (differential-tested in
 ``tests/test_continuous_batching.py``).
 
-With a ``mesh``, parameters are expected already placed on it (see
-``Model.init(key, mesh)``), the stacked caches are allocated on it under
-the sharding rules, and every jit'd piece traces under those rules.
+With the model's ``run.prefill_chunk`` n > 0, a prompt is prefilled in
+pieces of at most n tokens, each on the B=1 cache its earlier pieces
+filled. While any slot decodes, a step runs at most one piece before its
+decode step, so an admission holds the decoding slots back by one piece
+a step however long its prompt is; a step in which no slot decodes
+prefills whole prompts, as with n = 0.
+
+With a mesh (``mesh``, else the model's own layout, ``Model.mesh``),
+parameters are expected already placed on it (see ``Model.init``), the
+stacked caches are allocated on it under the sharding rules, and every
+jit'd piece traces under those rules.  ``param_bytes_per_chip`` and
+``cache_bytes_per_chip`` count what one device holds of each.
 
 Per-slot sampling keys are derived by ``fold_in(base_key, rid)`` so the
 token stream of one request never depends on which slot it landed in or
@@ -28,10 +37,10 @@ key stream, whose sampled (temperature > 0) outputs depend on
 scheduling order.  Greedy decoding is unaffected.
 
 The host's work is marked on the profiler's clock (``TraceAnnotation``):
-``serve.step`` around a step; in it ``serve.admit`` per admission (with
-``serve.prefill``, or ``serve.prefill.compile`` for the first prompt of
-a length, ``serve.first_token`` for the host's wait on it and
-``serve.write``), ``serve.decode`` (dispatch), ``serve.sync`` (the wait
+``serve.step`` around a step; in it ``serve.admit`` per admission, or
+per piece of one (with ``serve.prefill``, or ``serve.prefill.compile``
+for the first piece of a length and place in the prompt,
+``serve.first_token`` for the host's wait on it and ``serve.write``), ``serve.decode`` (dispatch), ``serve.sync`` (the wait
 for the step's tokens) and ``serve.emit`` (the per-slot bookkeeping and
 hooks).  With the profiler off a span costs under a microsecond.
 """
@@ -41,14 +50,15 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
-from typing import Deque, Dict, List, Optional, Set
+from typing import Any, Deque, Dict, List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.distributed.sharding import axis_rules, tree_shardings
+from repro.distributed.sharding import (axis_rules, per_device_bytes,
+                                        tree_shardings)
 from repro.models.model import Model, mask_padded_vocab
 from repro.serve.metrics import ServeMetrics
 
@@ -66,14 +76,26 @@ class Request:
     out: Optional[np.ndarray] = None
 
 
+@dataclasses.dataclass
+class _Filling:
+    """An admission whose prompt is being prefilled."""
+    req: Request
+    key: jax.Array                   # the slot's sampling key
+    sub: jax.Array                   # the first token's key
+    done: int = 0                    # prompt tokens prefilled so far
+    cache: Any = None                # the B=1 cache they filled
+
+
 class ContinuousEngine:
     """Slot-based continuous batching with a single batched decode step.
 
     API:
       ``submit(req)``   enqueue; ``False`` = queue full (backpressure).
-      ``step()``        admit into free slots, then one batched decode
-                        step across all occupied slots; returns the
-                        number of tokens emitted.
+      ``step()``        admit into free slots (one piece of a prompt
+                        at most while slots decode, where
+                        ``run.prefill_chunk`` is set), then one batched
+                        decode step across all occupied slots; returns
+                        the number of tokens emitted.
       ``serve(reqs)``   run a request list to completion (differential-
                         test convenience; bypasses the queue limit).
       ``results``       rid -> generated ids (np.int32) of finished
@@ -93,7 +115,8 @@ class ContinuousEngine:
         self.queue_limit = queue_limit
         self.metrics = metrics
         self.plan = plan                       # ServeCompilePlan or None
-        self.mesh = mesh
+        self.mesh = model.mesh if mesh is None else mesh
+        self.prefill_chunk = model.run.prefill_chunk
         self.base_key = jax.random.PRNGKey(seed)
 
         self.pending: Deque[Request] = collections.deque()
@@ -102,8 +125,10 @@ class ContinuousEngine:
         self._slot_hist: List[List[int]] = [[] for _ in range(self.slots)]
         self._slot_left = np.zeros(self.slots, np.int64)
         self._slot_len = np.zeros(self.slots, np.int64)
-        # prompt lengths prefilled so far: the first of each compiles
-        self._prefill_lengths: Set[int] = set()
+        self._filling: Optional[_Filling] = None
+        # prefill programs run so far, (first piece?, length): the first
+        # run of each compiles
+        self._prefill_programs: Set[Tuple[bool, int]] = set()
         self.prefill_compiles = 0
         # of the cache's per-layer leaves, those the decode step writes in
         # place as the layer scan's carry (appended attention K/V)
@@ -115,18 +140,21 @@ class ContinuousEngine:
             model.cache_shapes(1, self.max_len))
         zeros = lambda: jax.tree.map(lambda l: jnp.zeros(l.shape, l.dtype),
                                      shapes)
-        if mesh is None:
+        if self.mesh is None:
             self._stacked = zeros()
         else:
             axes = jax.tree.map(lambda a: "- " + a,
                                 model.cache_axes(1, self.max_len))
             self._stacked = jax.jit(zeros, out_shardings=tree_shardings(
-                axes, shapes, mesh))()
+                axes, shapes, self.mesh))()
+        self.param_bytes_per_chip = per_device_bytes(params)
+        self.cache_bytes_per_chip = per_device_bytes(self._stacked)
         self._tok = jnp.zeros((self.slots, 1), jnp.int32)
         self._keys = jnp.stack([jax.random.fold_in(self.base_key, s)
                                 for s in range(self.slots)])
 
         self._prefill_one = jax.jit(self._prefill)
+        self._extend_one = jax.jit(self._extend, donate_argnums=(1,))
         self._write_slot = jax.jit(self._write, donate_argnums=(0, 1, 2))
         self._decode_all = jax.jit(self._batched_step, donate_argnums=(1,))
 
@@ -143,6 +171,12 @@ class ContinuousEngine:
         logits, cache1, _ = self.model.apply(params, tokens1, cache=cache1)
         tok = self._sample(logits[:, -1], key)
         return tok, cache1
+
+    def _extend(self, params, cache1, tokens1, key):
+        """The next piece of a prompt, on the B=1 cache its earlier
+        pieces filled."""
+        logits, cache1, _ = self.model.apply(params, tokens1, cache=cache1)
+        return self._sample(logits[:, -1], key), cache1
 
     def _write(self, stacked, tok_all, keys_all, cache1, tok0, key, s):
         """Write one prefilled B=1 cache into slot ``s``'s rows."""
@@ -196,7 +230,8 @@ class ContinuousEngine:
 
     @property
     def busy(self) -> bool:
-        return bool(self.pending) or self.active_slots > 0
+        return bool(self.pending) or self._filling is not None or \
+            self.active_slots > 0
 
     def submit(self, req: Request, arrival: Optional[float] = None) -> bool:
         """Enqueue; ``False`` (and no enqueue) when the admission queue
@@ -211,23 +246,26 @@ class ContinuousEngine:
         self.pending.append(req)
         return True
 
-    def _admit(self, s: int) -> bool:
-        """Prefill the next pending request into free slot ``s``."""
-        while self.pending:
+    def _admit(self, s: int, whole: bool = True) -> bool:
+        """Prefill the next pending request into free slot ``s``.  With
+        ``whole`` False, only the next piece of its prompt, the rest left
+        to later steps.  True once slot ``s`` holds the request."""
+        while self.pending or self._filling is not None:
             with TraceAnnotation("serve.admit"):
-                req = self.pending.popleft()
-                key = jax.random.fold_in(self.base_key, req.rid)
-                key, sub = jax.random.split(key)
-                compiles = len(req.prompt) not in self._prefill_lengths
-                if compiles:
-                    self._prefill_lengths.add(len(req.prompt))
-                    self.prefill_compiles += 1
-                with TraceAnnotation("serve.prefill.compile" if compiles
-                                     else "serve.prefill"):
-                    tok0, cache = self._prefill_one(
-                        self.params, jnp.asarray(req.prompt[None, :]), sub)
+                if self._filling is None:
+                    req = self.pending.popleft()
+                    key = jax.random.fold_in(self.base_key, req.rid)
+                    key, sub = jax.random.split(key)
+                    self._filling = _Filling(req, key, sub)
+                f = self._filling
+                req = f.req
+                tok0 = self._prefill_piece(f)
+                while whole and f.done < len(req.prompt):
+                    tok0 = self._prefill_piece(f)
+                if f.done < len(req.prompt):
+                    return False
+                self._filling = None
                 if self.metrics:
-                    self.metrics.clock.advance(VIRTUAL_PREFILL_COST)
                     self.metrics.on_admit(req.rid, len(req.prompt))
                 with TraceAnnotation("serve.first_token"):
                     first = int(tok0[0])
@@ -239,17 +277,43 @@ class ContinuousEngine:
                     self.results[req.rid] = np.asarray([first], np.int32)
                     if self.metrics:
                         self.metrics.on_finish(req.rid)
-                    continue
+                    if whole:
+                        continue
+                    return False
                 with TraceAnnotation("serve.write"):
                     self._stacked, self._tok, self._keys = self._write_slot(
-                        self._stacked, self._tok, self._keys, cache, tok0,
-                        key, jnp.int32(s))
+                        self._stacked, self._tok, self._keys, f.cache, tok0,
+                        f.key, jnp.int32(s))
                 self._slot_req[s] = req
                 self._slot_hist[s] = [first]
                 self._slot_left[s] = req.max_new - 1
                 self._slot_len[s] = len(req.prompt)
                 return True
         return False
+
+    def _prefill_piece(self, f: _Filling) -> jax.Array:
+        """Prefill the next piece of ``f``'s prompt, the whole rest of it
+        where ``prefill_chunk`` is 0; the token sampled after it."""
+        n = len(f.req.prompt) - f.done
+        if self.prefill_chunk > 0:
+            n = min(n, self.prefill_chunk)
+        piece = jnp.asarray(f.req.prompt[None, f.done:f.done + n])
+        program = (f.done == 0, n)
+        compiles = program not in self._prefill_programs
+        if compiles:
+            self._prefill_programs.add(program)
+            self.prefill_compiles += 1
+        with TraceAnnotation("serve.prefill.compile" if compiles
+                             else "serve.prefill"):
+            if f.done == 0:
+                tok0, f.cache = self._prefill_one(self.params, piece, f.sub)
+            else:
+                tok0, f.cache = self._extend_one(self.params, f.cache,
+                                                 piece, f.sub)
+        f.done += n
+        if self.metrics:
+            self.metrics.clock.advance(VIRTUAL_PREFILL_COST)
+        return tok0
 
     def _finish(self, s: int) -> None:
         req = self._slot_req[s]
@@ -266,8 +330,12 @@ class ContinuousEngine:
             return self._step()
 
     def _step(self) -> int:
-        for s in range(self.slots):
-            if self._slot_req[s] is None:
+        free = [s for s in range(self.slots) if self._slot_req[s] is None]
+        if self.prefill_chunk > 0 and len(free) < self.slots:
+            if free:                          # slots decode: one piece
+                self._admit(free[0], whole=False)
+        else:
+            for s in free:
                 self._admit(s)
         active = np.asarray([r is not None for r in self._slot_req])
         if self.metrics:

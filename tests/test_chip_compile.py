@@ -183,3 +183,57 @@ def test_batched_step_keeps_cache_in_place(one_chip, monkeypatch):
     operands = re.findall(r"\w+\[([\d,]*)\]", calls[0].split(
         "operand_layout_constraints=", 1)[1].split("}}", 1)[0])
     assert operands[2] == f"{slots},{cfg.num_kv_heads},{max_len},128"
+
+
+def test_batched_step_on_four_chips(topo, monkeypatch):
+    """``qwen2_7b_28l.decode_4chip``'s decode step: the whole 28 layers
+    at 64 slots x 4096 rows, bf16, on ``data=1, model=4`` of the described
+    v5e:2x2.  Each chip holds a quarter of the weights and one KV head of
+    the cache (under 8 GiB of its 16 GB), the decode kernel runs under
+    ``shard_map`` on that one head of every slot, and the only
+    collectives are the layers' all-reduces and the sampling's small
+    gathers: no stack is copied or moved between chips."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.distributed.sharding import axis_rules, tree_shardings
+    from repro.launch.mesh import make_mesh
+    monkeypatch.setattr(decode_mod, "pallas_interpret",
+                        lambda interpret=None: False)      # Mosaic
+    slots, max_len = 64, 4096
+    mesh = make_mesh((1, 4), ("data", "model"), devices=topo.devices)
+    model = Model(get_config("qwen2_7b"),
+                  RunConfig(param_dtype="bfloat16",
+                            activation_dtype="bfloat16",
+                            cache_dtype="bfloat16", backend="pallas",
+                            max_seq=max_len))
+    eng = ContinuousEngine.__new__(ContinuousEngine)       # nothing allocated
+    eng.model, eng.max_len, eng.temperature, eng.mesh = \
+        model, max_len, 0.0, mesh
+    put = lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
+    rep = NamedSharding(mesh, P())
+    shapes = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct((slots,) + l.shape, l.dtype),
+        model.cache_shapes(1, max_len))
+    axes = jax.tree.map(lambda a: "- " + a, model.cache_axes(1, max_len))
+    args = (jax.tree.map(put, model.param_shapes(), tree_shardings(
+                model.param_axes(), model.param_shapes(), mesh)),
+            jax.tree.map(put, shapes, tree_shardings(axes, shapes, mesh)),
+            put(jax.ShapeDtypeStruct((slots, 1), jnp.int32), rep),
+            put(jax.ShapeDtypeStruct((slots,), bool), rep),
+            put(jax.ShapeDtypeStruct((slots, 2), jnp.uint32), rep))
+    with axis_rules(mesh):
+        c = jax.jit(eng._batched_step, donate_argnums=(1,)).lower(
+            *args).compile()
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes < 8 * 2**30
+    assert mem.temp_size_in_bytes <= 300_000_000
+    text = c.as_text()
+    calls = [l for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 1                      # once per layer, in the scan
+    operands = re.findall(r"\w+\[([\d,]*)\]", calls[0].split(
+        "operand_layout_constraints=", 1)[1].split("}}", 1)[0])
+    assert operands[2] == f"{slots},1,{max_len},128"    # one KV head here
+    kinds = set(re.findall(
+        r"= \S+ (all-reduce|all-gather|reduce-scatter|collective-permute|"
+        r"all-to-all)[\w-]*\(", text))
+    assert kinds == {"all-reduce", "all-gather"}, kinds

@@ -68,6 +68,17 @@ def test_four_chip_phase_on_host_devices():
     assert "1-layer first decode logits sharded vs device 0" in r.stdout
 
 
+def test_four_chip_phase_at_one_device(capsys):
+    """The same phase at ``model_parallel=1``: the model builds no mesh
+    and the engine serves on one device."""
+    chip_smoke.phase_four_chips(reduced(get_config("qwen2_7b")), cut=1,
+                                n_requests=2, slots=2, prompt=(4, 8),
+                                out=(2, 4), model_parallel=1)
+    out = capsys.readouterr().out
+    assert "model_parallel=1" in out
+    assert "sharded engine: requests=2" in out
+
+
 @pytest.mark.parametrize("env_dir", [True, False])
 def test_compile_cache_directory(tmp_path, env_dir):
     code = ("import jax, jax.numpy as jnp;"

@@ -3,6 +3,8 @@ generation exactly (greedy), regardless of slot scheduling order — and
 the batched engine (one vmap'd jit'd decode step across all slots) must
 be bit-identical to the serial per-slot reference engine."""
 
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -277,3 +279,82 @@ def test_prefill_compiles_counts_new_lengths(setup):
                        max_new=2) for i, n in enumerate([6, 4])])
     assert eng.prefill_compiles == 3
     assert eng._prefill_one._cache_size() == 3    # what jit compiled
+
+
+def _pieces_of(model, n):
+    """``model`` with its run prefilling prompts ``n`` tokens a step."""
+    return Model(model.cfg, dataclasses.replace(model.run, prefill_chunk=n))
+
+
+def _pieces_per_step(eng):
+    """Wrap ``eng``'s prefill programs to count the pieces each step
+    runs; returns the list of [slots decoding at the step's start,
+    pieces] the counts go to."""
+    counts, prefill, extend = [], eng._prefill_one, eng._extend_one
+
+    def counted(fn):
+        def run(*a):
+            counts[-1][1] += 1
+            return fn(*a)
+        return run
+
+    step = eng._step
+
+    def stepped():
+        counts.append([eng.active_slots, 0])
+        return step()
+
+    eng._prefill_one, eng._extend_one = counted(prefill), counted(extend)
+    eng._step = stepped
+    return counts
+
+
+@pytest.mark.parametrize("fixture", ["setup", "setup_pallas"])
+def test_piecewise_prefill_streams_equal_whole_prompts(fixture, request):
+    """Prompts prefilled in pieces of 3 tokens give the same greedy
+    streams as whole prompts, max_new=1 requests included."""
+    cfg, model, params = request.getfixturevalue(fixture)
+    reqs = _mixed_requests(cfg, n=7)
+    whole = ContinuousEngine(model, params, slots=2, max_len=64)
+    want = whole.serve([Request(r.rid, r.prompt, r.max_new) for r in reqs])
+    eng = ContinuousEngine(_pieces_of(model, 3), params, slots=2, max_len=64)
+    got = eng.serve([Request(r.rid, r.prompt, r.max_new) for r in reqs])
+    assert sorted(got) == sorted(want)
+    for rid in want:
+        np.testing.assert_array_equal(got[rid], want[rid])
+
+
+def test_piecewise_prefill_runs_one_piece_while_slots_decode(setup):
+    """A step with no slot decoding prefills whole prompts, every free
+    slot filled; while slots decode, a step runs one piece at most."""
+    cfg, model, params = setup
+    eng = ContinuousEngine(_pieces_of(model, 4), params, slots=3, max_len=64)
+    prefill, extend = eng._prefill_one, eng._extend_one
+    counts = _pieces_per_step(eng)
+    rng = np.random.default_rng(5)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, (10,))
+                    .astype(np.int32), max_new=3 + i) for i in range(6)]
+    for r in reqs:
+        eng.submit(r)
+    eng.step()
+    assert counts == [[0, 9]]             # 3 prompts of 10 tokens: 4+4+2
+    assert eng.active_slots == 3
+    eng.drain()
+    assert max(n for active, n in counts if active) == 1
+    assert any(n == 0 for active, n in counts if active)
+    assert sum(n for _, n in counts) == 6 * 3
+    assert sorted(eng.results) == list(range(6))
+    assert [len(eng.results[i]) for i in range(6)] == [3 + i
+                                                       for i in range(6)]
+    # pieces of 4 and 2, first or later in a prompt: three programs
+    assert eng.prefill_compiles == 3
+    assert prefill._cache_size() == 1
+    assert extend._cache_size() == 2
+
+
+def test_prefill_chunk_comes_from_the_run(setup):
+    cfg, model, params = setup
+    assert ContinuousEngine(model, params, slots=2,
+                            max_len=64).prefill_chunk == 0
+    assert ContinuousEngine(_pieces_of(model, 16), params, slots=2,
+                            max_len=64).prefill_chunk == 16

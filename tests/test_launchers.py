@@ -10,12 +10,13 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(args, timeout=600):
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
-    env.pop("XLA_FLAGS", None)
+def _run(args, timeout=600, env=None):
+    full = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    full.pop("XLA_FLAGS", None)
+    full.update(env or {})
     return subprocess.run([sys.executable, "-m"] + args,
                           capture_output=True, text=True, timeout=timeout,
-                          env=env, cwd=REPO)
+                          env=full, cwd=REPO)
 
 
 @pytest.mark.slow
@@ -70,3 +71,25 @@ def test_serve_continuous_cli_reports_inplace_cache_leaves():
     inplace, total = re.search(r"inplace_cache_leaves=(\d+)/(\d+)",
                                line).groups()
     assert int(inplace) == 0 < int(total)
+
+
+@pytest.mark.parametrize("n", [1, 4])
+def test_serve_continuous_cli_model_parallel(n):
+    """``--model-parallel N`` serves one replica over N devices (four host
+    devices here) and reports the layout and what one chip holds."""
+    extra = {"XLA_FLAGS": "--xla_force_host_platform_device_count=4"} \
+        if n > 1 else {}
+    r = _run(["repro.launch.serve", "--arch", "qwen2-7b", "--reduced",
+              "--continuous", "--slots", "2", "--requests", "3",
+              "--prompt-len", "6", "--gen", "3", "--rate", "50",
+              "--model-parallel", str(n)], env=extra)
+    assert r.returncode == 0, r.stderr[-2000:]
+    line = next(l for l in r.stdout.splitlines()
+                if l.startswith("[serve] continuous:"))
+    assert f"layout=data1xmodel{n} " in line
+    params = int(re.search(r"param_bytes_per_chip=(\d+)", line)[1])
+    cache = int(re.search(r"cache_bytes_per_chip=(\d+)", line)[1])
+    total = int(re.search(r"params=([\d,]+)", r.stdout)[1].replace(",", ""))
+    assert 0 < cache
+    # f32 weights; the replicated norms add a little to a chip's share
+    assert total * 4 / n <= params < 1.1 * total * 4 / n
