@@ -7,7 +7,9 @@ TPU adaptation notes:
     (``kernels.ssd_scan``) so the MXU does the work — the paper's
     "GEMM-ification of tensor ops" future-work item;
   * decode is a single recurrence step on cached state (constant memory —
-    these are the archs that make the 500k-context cell feasible).
+    these are the archs that make the 500k-context cell feasible); the
+    SSD state steps in place in the layer scan's carried stack
+    (``kernels.ssd_state_step``).
 """
 
 from __future__ import annotations
@@ -17,8 +19,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import shard
+from repro.distributed.sharding import current_mesh, shard
 from repro.kernels import ops as kops
+from repro.kernels.ssd_state_step import ssd_state_step, ssd_state_step_ref
 from repro.models.layers import Maker, Params, rmsnorm
 
 
@@ -148,8 +151,15 @@ def _split_ssd(proj: jax.Array, di: int, N: int, H: int):
 
 
 def apply_ssd(p: Params, x: jax.Array, cfg,
-              cache: Optional[Params] = None, backend: str = "xla"
+              cache: Optional[Params] = None, backend: str = "xla",
+              layer: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, Optional[Params]]:
+    """Mamba2 SSD block.  ``cache`` holds the state (B, N, H*P) and the
+    convolution window (B, W-1, C) before this call; with ``layer``, the
+    (L, ...) stacks of a layer scan, stepped at ``layer`` where they lie,
+    and the returned cache is the whole stack.  Decode (S == 1) steps the
+    state with :func:`ssd_state_step`, the Mosaic kernel with ``backend``
+    "pallas" off a mesh, else its jnp form."""
     B, S, d = x.shape
     s = cfg.ssm
     di = s.d_inner or 2 * d
@@ -159,7 +169,13 @@ def apply_ssd(p: Params, x: jax.Array, cfg,
     h_in = rmsnorm(x, p["norm"], cfg.norm_eps)
     proj = jnp.einsum("bsd,dk->bsk", h_in, p["in_proj"])
     z, xbc, dt = _split_ssd(proj, di, N, H)
-    conv_state = cache["conv"] if cache is not None else None
+    conv_state = states = None
+    if cache is not None:
+        # a single layer's cache is stepped as a stack of one
+        states, convs = ((cache["state"], cache["conv"]) if layer is not None
+                         else (cache["state"][None], cache["conv"][None]))
+        at = jnp.int32(0) if layer is None else layer
+        conv_state = jax.lax.dynamic_index_in_dim(convs, at, 0, False)
     xbc, new_conv = causal_conv1d(xbc, p["conv"], conv_state)
     xbc = jax.nn.silu(xbc)
     xs = xbc[..., :di].reshape(B, S, H, P)
@@ -168,28 +184,40 @@ def apply_ssd(p: Params, x: jax.Array, cfg,
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
 
-    h0 = cache["state"] if cache is not None else None
     if S == 1 and cache is not None:
         # single-token recurrence (decode)
-        decay = jnp.exp(dt[:, 0] * A[None, :])                    # (B, H)
-        dBx = (dt[:, 0, :, None, None] * xs[:, 0, :, :, None]
-               * Bmat[:, 0, None, None, :])                       # (B,H,P,N)
-        h_new = h0 * decay[..., None, None] + dBx
-        y = jnp.einsum("bhpn,bn->bhp", h_new, Cmat[:, 0].astype(jnp.float32)) \
+        decay = jnp.repeat(jnp.exp(dt[:, 0] * A[None, :]), P, axis=-1)
+        dtx = (dt[:, 0, :, None] * xs[:, 0]).reshape(B, H * P)
+        # a Mosaic kernel is not partitioned: under a mesh, the jnp form
+        kernel = backend == "pallas" and current_mesh() is None
+        step = ssd_state_step if kernel else ssd_state_step_ref
+        y, states = step(states, at, decay, dtx,
+                         Bmat[:, 0].astype(jnp.float32),
+                         Cmat[:, 0].astype(jnp.float32))
+        y = y.reshape(B, H, P) \
             + p["D"].astype(jnp.float32)[None, :, None] * xs[:, 0]
         y = y[:, None].reshape(B, S, di).astype(x.dtype)
-        new_state = h_new
     else:
-        y, new_state = _ssd_with_state(xs, dt, A, Bmat, Cmat, p["D"],
-                                       h0, s.chunk, backend)
+        h0 = None
+        if cache is not None:                 # (B, N, H*P) -> (B, H, P, N)
+            h0 = jax.lax.dynamic_index_in_dim(states, at, 0, False)
+            h0 = h0.reshape(B, N, H, P).transpose(0, 2, 3, 1)
+        y, h_fin = _ssd_with_state(xs, dt, A, Bmat, Cmat, p["D"],
+                                   h0, s.chunk, backend)
         y = y.reshape(B, S, di)
+        if cache is not None:
+            h_fin = h_fin.transpose(0, 3, 1, 2).reshape(B, N, H * P)
+            states = jax.lax.dynamic_update_index_in_dim(
+                states, h_fin.astype(states.dtype), at, 0)
     y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
                 p["out_norm"], cfg.norm_eps)
     out = jnp.einsum("bsk,kd->bsd", y, p["out_proj"])
     new_cache = None
     if cache is not None:
-        new_cache = {"state": new_state.astype(cache["state"].dtype),
-                     "conv": new_conv}
+        convs = jax.lax.dynamic_update_index_in_dim(
+            convs, new_conv.astype(convs.dtype), at, 0)
+        new_cache = ({"state": states, "conv": convs} if layer is not None
+                     else {"state": states[0], "conv": convs[0]})
     return x + shard(out, "batch", None, None), new_cache
 
 
@@ -240,12 +268,12 @@ def _ssd_with_state(xs, dt, A, Bmat, Cmat, D, h0, chunk, backend):
 
 
 def ssd_cache_spec(cfg, batch: int, dtype) -> dict:
+    """The state, float32, state-major as the decode kernel reads it:
+    (batch, N, H*P); the convolution window of the last W-1 inputs."""
     s = cfg.ssm
     di = s.d_inner or 2 * cfg.d_model
-    H = di // s.head_dim
     return {
-        "state": jax.ShapeDtypeStruct((batch, H, s.head_dim, s.state_dim),
-                                      jnp.float32),
+        "state": jax.ShapeDtypeStruct((batch, s.state_dim, di), jnp.float32),
         "conv": jax.ShapeDtypeStruct((batch, s.conv_width - 1,
                                       di + 2 * s.state_dim), dtype),
     }

@@ -75,8 +75,9 @@ def _apply_layer(kind: str, p: Params, x: jax.Array, cfg: ModelConfig,
                  positions: jax.Array, window, cache, kv_len,
                  backend: str, enc_kv=None, layer=None):
     """Returns (x, new_cache, aux_loss).  ``layer``: the scan index at
-    which the appended K/V stacks in ``cache["attn"]`` are written and
-    read (see :func:`_split_carried`)."""
+    which the carried stacks in the cache (``cache["attn"]``'s appended
+    K/V, ``cache["ssd"]``'s state and window) are written and read (see
+    :func:`_split_carried`)."""
     cache = cache if cache else None
     aux = jnp.float32(0.0)
     if kind in ("attn", "dense_moe", "moe", "xdec"):
@@ -111,7 +112,7 @@ def _apply_layer(kind: str, p: Params, x: jax.Array, cfg: ModelConfig,
     if kind == "ssd":
         x, nc = rec_mod.apply_ssd(p["ssd"], x, cfg,
                                   cache.get("ssd") if cache else None,
-                                  backend=backend)
+                                  backend=backend, layer=layer)
         return x, ({"ssd": nc} if nc is not None else None), aux
     raise ValueError(kind)
 
@@ -121,6 +122,15 @@ def _appends_kv(cfg: ModelConfig, kind: str) -> bool:
     call appends rows to (and does not rewrite whole)."""
     return kind in ("attn", "xdec") or (kind in ("moe", "dense_moe")
                                         and cfg.mla is None)
+
+
+def _carried_key(cfg: ModelConfig, kind: str) -> Optional[str]:
+    """The part of a layer's cache that a call updates in place and so
+    rides the layer scan's carry: appended attention K/V ("attn"), or the
+    SSD state and convolution window ("ssd"); None for the rest."""
+    if _appends_kv(cfg, kind):
+        return "attn"
+    return "ssd" if kind == "ssd" else None
 
 
 def _layer_cache_spec(cfg: ModelConfig, kind: str, batch: int, max_len: int,
@@ -153,7 +163,7 @@ _CACHE_AXES = {"k": "batch kv_heads kv_seq -", "v": "batch kv_heads kv_seq -",
                "ckv": "batch kv_seq -", "kr": "batch kv_seq -",
                "xk": "batch - heads -", "xv": "batch - heads -",
                "h": "batch ff", "conv": "batch - ff",
-               "state": "batch heads - -"}
+               "state": "batch - heads"}
 
 
 def _cache_axes(spec) -> Any:
@@ -303,17 +313,20 @@ def _split_carried(cfg: ModelConfig, plan: StackPlan, scan_cache):
     """Split the scanned groups' caches, per pattern position, into
     (carried, rest).
 
-    ``carried``: the appended attention K/V stacks ({"k", "v"}, or None
-    where the position has none).  They ride the layer scan's carry:
-    each layer writes its new rows where the stack lies and reads its
-    own layer of it, so a decode step copies no stack in or out.
-    ``rest``: every other leaf (SSD and RG-LRU states, MLA's compressed
-    cache, cross-attention K/V: states a call rewrites whole), sliced
-    per layer as the scan's ``xs`` and stacked back as its ``ys``."""
-    carried = tuple(c["attn"] if c and _appends_kv(cfg, kind) else None
-                    for kind, c in zip(plan.pattern, scan_cache))
-    rest = tuple({n: v for n, v in c.items() if kv is None or n != "attn"}
-                 for c, kv in zip(scan_cache, carried))
+    ``carried``: the stacks of the position's :func:`_carried_key` (the
+    appended attention K/V, or the SSD state and convolution window), or
+    None where it has none.  They ride the layer scan's carry: each layer
+    writes its update where the stack lies and reads its own layer of it,
+    so a decode step copies no stack in or out.  ``rest``: every other
+    leaf (RG-LRU states, MLA's compressed cache, cross-attention K/V),
+    sliced per layer as the scan's ``xs`` and stacked back as its
+    ``ys``."""
+    keys = [_carried_key(cfg, kind) if c else None
+            for kind, c in zip(plan.pattern, scan_cache)]
+    carried = tuple(None if k is None else c[k]
+                    for k, c in zip(keys, scan_cache))
+    rest = tuple({n: v for n, v in c.items() if n != k}
+                 for k, c in zip(keys, scan_cache))
     return carried, rest
 
 
@@ -415,23 +428,24 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array, *,
                       if cache is not None else
                       tuple({} for _ in plan.pattern))
         carried, scan_cache = _split_carried(cfg, plan, scan_cache)
+        keys = [_carried_key(cfg, kind) for kind in plan.pattern]
 
         def body(carry, xs):
-            x, kv = carry
+            x, cs = carry
             p_sl, c_sl, w_sl, g = xs
-            ncs, kvs = [], []
+            ncs, new_cs = [], []
             aux_g = jnp.float32(0.0)
             for i, kind in enumerate(plan.pattern):
-                c = c_sl[i] if kv[i] is None else {**c_sl[i], "attn": kv[i]}
+                c = c_sl[i] if cs[i] is None else {**c_sl[i], keys[i]: cs[i]}
                 x, nc, aux = _apply_layer(kind, p_sl[i], x, cfg, positions,
                                           w_sl[i], c, kv_len, backend,
                                           enc_kv_fn,
-                                          layer=None if kv[i] is None else g)
+                                          layer=None if cs[i] is None else g)
                 nc = dict(nc) if nc is not None else {}
-                kvs.append(None if kv[i] is None else nc.pop("attn"))
+                new_cs.append(None if cs[i] is None else nc.pop(keys[i]))
                 ncs.append(nc)
                 aux_g = aux_g + aux
-            return (x, tuple(kvs)), (tuple(ncs), aux_g)
+            return (x, tuple(new_cs)), (tuple(ncs), aux_g)
 
         if remat != "none":
             policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -444,9 +458,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array, *,
         aux_total += jnp.sum(auxs)
         if cache is not None:
             new_cache["scan"] = {
-                f"pos{i}": (scan_new_cache[i] if kv is None else
-                            {**scan_new_cache[i], "attn": kv})
-                for i, kv in enumerate(carried)}
+                f"pos{i}": (scan_new_cache[i] if c is None else
+                            {**scan_new_cache[i], keys[i]: c})
+                for i, c in enumerate(carried)}
 
     # ---- suffix layers (unrolled) ----
     for i in plan.suffix:

@@ -131,7 +131,8 @@ class ContinuousEngine:
         self._prefill_programs: Set[Tuple[bool, int]] = set()
         self.prefill_compiles = 0
         # of the cache's per-layer leaves, those the decode step writes in
-        # place as the layer scan's carry (appended attention K/V)
+        # place as the layer scan's carry (appended attention K/V, the
+        # SSD state and convolution window)
         self.inplace_cache_leaves, self.cache_leaves = \
             model.cache_leaf_counts()
 

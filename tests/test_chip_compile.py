@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 
 import repro.kernels.decode_attention as decode_mod
+import repro.kernels.ssd_state_step as ssd_mod
 from repro.configs.base import get_config, reduced
 from repro.core import frontend as fe
 from repro.core.pipeline import compile_gemm, compile_traced
@@ -126,6 +127,43 @@ def _elements(shape: str) -> int:
     return math.prod(int(n) for n in shape.split(",") if n)
 
 
+def _compile_batched_step(model, slots, max_len, sharding):
+    """The serving engine's decode step over ``slots`` slots, compiled
+    from shapes alone; and the stacked cache's shapes."""
+    eng = ContinuousEngine.__new__(ContinuousEngine)       # nothing allocated
+    eng.model, eng.max_len, eng.temperature = model, max_len, 0.0
+    eng.mesh = None
+    put = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding)
+    stacked = jax.tree.map(
+        lambda l: put(jax.ShapeDtypeStruct((slots,) + l.shape, l.dtype)),
+        model.cache_shapes(1, max_len))
+    args = (jax.tree.map(put, model.param_shapes()), stacked,
+            put(jax.ShapeDtypeStruct((slots, 1), jnp.int32)),
+            put(jax.ShapeDtypeStruct((slots,), bool)),
+            put(jax.ShapeDtypeStruct((slots, 2), jnp.uint32)))
+    c = jax.jit(eng._batched_step, donate_argnums=(1,)).lower(*args).compile()
+    return c, stacked
+
+
+def _writers_of(lines, size):
+    """(op, operand shapes, line) of every instruction of the compiled
+    program with an output of ``size`` elements."""
+    shapes = {}                                 # instruction -> output shapes
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ", line)
+        if m:
+            shapes[m[1]] = re.findall(r"\[([\d,]*)\]", m[2])
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (?:\(.*?\)|\S+) ([\w-]+)\((.*)",
+                     line)
+        if m and any(_elements(s) == size for s in shapes[m[1]]):
+            operands = re.findall(r"%([\w.-]+)", m[3].split(")")[0])
+            yield m[2], [shapes.get(o, [""])[0] for o in operands], line
+
+
+_PASSED_ALONG = ("parameter", "get-tuple-element", "tuple", "while", "bitcast")
+
+
 def test_batched_step_keeps_cache_in_place(one_chip, monkeypatch):
     """The decode step at ``qwen2_7b.decode_heavy``'s own shapes (7 layers,
     32 slots, 4096 rows, bf16) copies no K or V stack: it writes each new
@@ -143,39 +181,17 @@ def test_batched_step_keeps_cache_in_place(one_chip, monkeypatch):
                                  activation_dtype="bfloat16",
                                  cache_dtype="bfloat16", backend="pallas",
                                  max_seq=max_len))
-    eng = ContinuousEngine.__new__(ContinuousEngine)       # nothing allocated
-    eng.model, eng.max_len, eng.temperature, eng.mesh = model, max_len, 0.0, None
-    put = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
-    stacked = jax.tree.map(
-        lambda l: put(jax.ShapeDtypeStruct((slots,) + l.shape, l.dtype)),
-        model.cache_shapes(1, max_len))
-    args = (jax.tree.map(put, model.param_shapes()), stacked,
-            put(jax.ShapeDtypeStruct((slots, 1), jnp.int32)),
-            put(jax.ShapeDtypeStruct((slots,), bool)),
-            put(jax.ShapeDtypeStruct((slots, 2), jnp.uint32)))
-    c = jax.jit(eng._batched_step, donate_argnums=(1,)).lower(*args).compile()
+    c, stacked = _compile_batched_step(model, slots, max_len, one_chip)
 
     assert c.memory_analysis().temp_size_in_bytes <= 300_000_000
     stack = stacked["scan"]["pos0"]["attn"]["k"]
     row = slots * cfg.num_kv_heads * cfg.resolved_head_dim  # a row per slot
     lines = c.as_text().splitlines()
-    shapes = {}                                 # instruction -> output shapes
-    for line in lines:
-        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ", line)
-        if m:
-            shapes[m[1]] = re.findall(r"\[([\d,]*)\]", m[2])
-    for line in lines:
-        m = re.match(r"\s*(?:ROOT )?%(\S+) = (?:\(.*?\)|\S+) ([\w-]+)\((.*)",
-                     line)
-        if not m or not any(_elements(s) == stack.size
-                            for s in shapes[m[1]]):
-            continue
-        op, operands = m[2], re.findall(r"%([\w.-]+)", m[3].split(")")[0])
+    for op, operands, line in _writers_of(lines, stack.size):
         if op == "dynamic-update-slice":        # in place: what it writes
-            assert _elements(shapes[operands[1]][0]) <= row, line[:200]
+            assert _elements(operands[1]) <= row, line[:200]
         else:                                   # passed along, not copied
-            assert op in ("parameter", "get-tuple-element", "tuple",
-                          "while", "bitcast"), line[:200]
+            assert op in _PASSED_ALONG, line[:200]
 
     calls = [l for l in lines if 'custom_call_target="tpu_custom_call"' in l]
     assert len(calls) == 1                      # once per layer, in the scan
@@ -183,6 +199,43 @@ def test_batched_step_keeps_cache_in_place(one_chip, monkeypatch):
     operands = re.findall(r"\w+\[([\d,]*)\]", calls[0].split(
         "operand_layout_constraints=", 1)[1].split("}}", 1)[0])
     assert operands[2] == f"{slots},{cfg.num_kv_heads},{max_len},128"
+
+
+def test_batched_step_keeps_ssd_state_in_place(one_chip, monkeypatch):
+    """The decode step at ``mamba2_130m.chat_bursty``'s shapes (24 layers,
+    128 slots, ``max_len`` 1024, bf16, float32 state) copies no state
+    stack: one ``ssd_state_step`` call a layer, in the scan, steps every
+    slot's state where the stack lies, and nothing else writes it.
+
+    Passing the 2.4-GB state through the layer scan as ``xs``/``ys`` cost
+    a whole-stack relayout at entry, a write per layer and a third read
+    for the readout, and 2.4 GB of temporaries."""
+    monkeypatch.setattr(ssd_mod, "pallas_interpret",
+                        lambda interpret=None: False)      # Mosaic
+    slots, max_len = 128, 1024
+    model = Model(get_config("mamba2_130m"),
+                  RunConfig(param_dtype="bfloat16",
+                            activation_dtype="bfloat16",
+                            cache_dtype="bfloat16", backend="pallas",
+                            max_seq=max_len))
+    c, stacked = _compile_batched_step(model, slots, max_len, one_chip)
+
+    assert c.memory_analysis().temp_size_in_bytes <= 300_000_000
+    state = stacked["scan"]["pos0"]["ssd"]["state"]
+    assert state.shape == (slots, 24, 1, 128, 1536)
+    lines = c.as_text().splitlines()
+    for op, _, line in _writers_of(lines, state.size):
+        assert op in _PASSED_ALONG or (
+            op == "custom-call" and "ssd_state_step" in line), line[:200]
+    assert _kernel_names(c) == ["ssd_state_step"]
+    # the call lies in the body of the scan's while loop
+    body = None
+    for line in lines:
+        if re.match(r"\S", line):
+            body = line.split()[0].lstrip("%")
+        elif "tpu_custom_call" in line:
+            break
+    assert re.search(rf"while\(.*body=%{re.escape(body)}\b", "\n".join(lines))
 
 
 def test_batched_step_on_four_chips(topo, monkeypatch):

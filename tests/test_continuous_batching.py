@@ -116,10 +116,10 @@ def test_batched_bit_identical_to_serial(fixture, request):
         assert len(batched[r.rid]) == r.max_new
 
 
-@pytest.mark.parametrize("fixture,inplace", [("setup", 2), ("setup_ssm", 0)])
+@pytest.mark.parametrize("fixture,inplace", [("setup", 2), ("setup_ssm", 2)])
 def test_inplace_cache_leaves(fixture, inplace, request):
-    """Appended attention K/V (two leaves a layer kind) ride the layer
-    scan in place; SSD states, rewritten whole each step, do not."""
+    """Appended attention K/V, and the SSD state and convolution window
+    (two leaves a layer kind each), ride the layer scan in place."""
     cfg, model, params = request.getfixturevalue(fixture)
     eng = ContinuousEngine(model, params, slots=2, max_len=32)
     assert eng.inplace_cache_leaves == inplace

@@ -234,6 +234,53 @@ def test_decode_attention_vmap_broadcasts_unmapped(in_axes):
                                    atol=2e-5)
 
 
+# ---- SSD state step ----------------------------------------------------------
+
+
+@pytest.mark.parametrize("heads,headdim,d_state,slots,batch", [
+    (8, 16, 16, 3, 1),         # the reduced mamba2 the CPU tests serve
+    (24, 64, 128, 3, 1),       # mamba2_130m's heads, as the engine steps them
+    (24, 64, 128, 2, 16),      # a batch wider than one block of sequences
+])
+def test_ssd_state_step_vs_ref(heads, headdim, d_state, slots, batch):
+    """The kernel (interpreted here) steps one layer of the stack as the
+    jnp formula does and leaves every other layer bit-unchanged; under
+    vmap over slots it runs once, on the slot axis folded into its group
+    axis, and each slot's answer is the unbatched call's."""
+    from repro.kernels.ssd_state_step import (ssd_state_step,
+                                              ssd_state_step_ref)
+    rng = np.random.default_rng(heads + d_state + batch)
+    L, layer, W = 3, 2, heads * headdim
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    state = f32(slots, L, batch, d_state, W)
+    decay = jnp.repeat(jnp.exp(-0.1 * jnp.abs(f32(slots, batch, heads))),
+                       headdim, axis=-1)
+    dtx, bmat, cmat = (0.1 * f32(slots, batch, W), f32(slots, batch, d_state),
+                       f32(slots, batch, d_state))
+    args = (state, decay, dtx, bmat, cmat)
+    step = lambda s, *a: ssd_state_step(s, layer, *a)
+    y, new = jax.vmap(step)(*args)
+
+    want_y, want = jax.vmap(lambda s, *a: ssd_state_step_ref(s, layer, *a))(
+        *args)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(new[:, layer]),
+                               np.asarray(want[:, layer]),
+                               rtol=1e-6, atol=1e-6)
+    others = [i for i in range(L) if i != layer]
+    np.testing.assert_array_equal(np.asarray(new[:, others]),
+                                  np.asarray(state[:, others]))
+    for i in range(slots):
+        one_y, one = step(*(a[i] for a in args))
+        np.testing.assert_array_equal(np.asarray(one_y), np.asarray(y[i]))
+        np.testing.assert_array_equal(np.asarray(one), np.asarray(new[i]))
+    calls = _named_calls(jax.make_jaxpr(jax.vmap(step))(*args).jaxpr,
+                         "ssd_state_step")
+    assert len(calls) == 1
+    assert calls[0].invars[-1].aval.shape == (slots, L, batch, d_state, W)
+
+
 _SHARDED_DECODE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_config, reduced
