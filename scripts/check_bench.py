@@ -5,8 +5,7 @@ schema here, mapped to the benchmark module that owns the matching
 ``check_bench(doc)`` gate.  This script loads each committed file,
 dispatches on its ``schema`` field, and fails loudly on: unknown
 schemas, files that no checker claims, or any gate violation (e.g. a
-fabric entry whose overlap speedup slipped below the 1.3x floor, or a
-serve entry with a malformed latency histogram).
+fabric entry whose overlap speedup slipped below the 1.3x floor).
 
   PYTHONPATH=src python scripts/check_bench.py [FILES...]
 
@@ -24,7 +23,6 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 #: schema tag -> benchmark module (relative to repo root) owning check_bench
 REGISTRY = {
-    "serve_bench/v1": "benchmarks/serve_bench.py",
     "area_bench/v1": "benchmarks/area_bench.py",
     "fabric_bench/v1": "benchmarks/fabric_bench.py",
 }

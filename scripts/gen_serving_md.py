@@ -1,10 +1,9 @@
 #!/usr/bin/env python
 """Generate docs/SERVING.md — the serving-under-load subsystem guide.
 
-Every transcript is produced by actually running the load generator,
-the batched continuous engine and the per-block compile plan in-process
-under a virtual clock, so the document cannot drift from the runtime's
-real behaviour: CI regenerates it and fails on any diff (same contract
+Every transcript is produced by actually running the load generator
+and the per-block compile plan in-process, so the document cannot drift
+from the runtime's real behaviour: CI regenerates it and fails on any diff (same contract
 as docs/RAISING.md / docs/DSE.md / docs/REWRITE.md).
 
     PYTHONPATH=src python scripts/gen_serving_md.py > docs/SERVING.md
@@ -13,23 +12,10 @@ as docs/RAISING.md / docs/DSE.md / docs/REWRITE.md).
 
 from __future__ import annotations
 
-import importlib.util
-import json
-import os
 import sys
 
 from repro.serve import loadgen
 from repro.serve.compiled import plan_blocks
-
-_BENCH = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                      "serve_bench.py")
-
-
-def _load_bench():
-    spec = importlib.util.spec_from_file_location("serve_bench", _BENCH)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def loadgen_transcript() -> str:
@@ -47,23 +33,12 @@ def loadgen_transcript() -> str:
     return "\n".join(lines)
 
 
-def serve_transcript(bench) -> str:
-    entry = bench.run_config(
-        "qwen2_7b", slots=2, requests=8, rate=6.0, process="poisson",
-        seed=0, clock_kind="virtual", queue_limit=4, prompt_hi=7,
-        out_hi=5, with_plan=False, max_len=32)
-    snap = json.dumps(entry["metrics"], indent=2, sort_keys=True)
-    return bench.fmt_entry(entry) + "\n" + snap
-
-
 def plan_transcript() -> str:
     return plan_blocks("qwen2_7b").describe()
 
 
 def main() -> int:
-    bench = _load_bench()
     lg = loadgen_transcript()
-    serve = serve_transcript(bench)
     plan = plan_transcript()
 
     print(f"""\
@@ -76,8 +51,10 @@ The paper measures its compiler by what the generated designs do under
 real workloads; this repo's equivalent is `repro.serve`: a
 serving-under-load subsystem that drives the model registry's reduced
 configs with deterministic request streams, batches decode across
-requests in ONE jit'd step, and records tail latency into the repo's
-perf trajectory (`BENCH_serve.json`).
+requests in ONE jit'd step, and records tail latency per request.  Its
+speed on the chip is measured by the on-chip benchmark
+(`BENCHMARK.json`, `bench/run.py`), whose runs are recorded in
+`PERF_LEDGER.jsonl`.
 
 Four layers, each usable alone:
 
@@ -132,12 +109,8 @@ buckets at 2% growth, so p50/p90/p99 are recovered within ~2% at O(1)
 memory.  TTFT is measured from *arrival* (queueing included), TPOT is
 the gap between consecutive decode tokens, queue depth and slot
 occupancy are sampled once per engine step.  Time comes from a `Clock`:
-`WallClock` for real runs, `VirtualClock` (each step advances a fixed
-virtual cost) for byte-reproducible transcripts like this one:
-
-```
-{serve}
-```
+`WallClock` for real runs, `VirtualClock` (time moves only when the
+caller advances it) for tests.
 
 ## The compiler bridge
 
@@ -146,28 +119,12 @@ virtual cost) for byte-reproducible transcripts like this one:
 PassManager stack under the autotuner's schedule for its dominant
 matmul shape (falling back through `tpu_mxu` to the always-legal
 nested schedule), validates against the traced reference on real
-inputs, and records explicit plain-jit fallbacks with reasons — a
-`BENCH_serve.json` entry always states exactly which blocks of the
-serving model ran through the compiler:
+inputs, and records explicit plain-jit fallbacks with reasons — the
+plan states exactly which blocks of the serving model compile through
+the stack:
 
 ```
 {plan}
-```
-
-## The recorded trajectory: BENCH_serve.json
-
-`benchmarks/serve_bench.py` drives sustained mixed prefill/decode load
-over ≥2 reduced configs and writes `BENCH_serve.json`
-(schema `serve_bench/v1`): per config/workload, tokens/sec, p50/p90/p99
-TTFT and TPOT, e2e latency, queue depth, slot utilization, requests
-completed, plus the embedded compile plan.  `check_bench` is the CI
-schema gate (`serve-smoke` job).
-
-```sh
-PYTHONPATH=src python benchmarks/serve_bench.py                 # 2 configs
-PYTHONPATH=src python benchmarks/serve_bench.py --smoke         # CI seconds
-PYTHONPATH=src python benchmarks/serve_bench.py --clock virtual # replayable
-PYTHONPATH=src python benchmarks/serve_bench.py --mesh data=2   # sharded
 ```
 
 ## API
@@ -185,7 +142,7 @@ for r in stream:
     while not engine.submit(Request(r.rid, r.prompt, r.max_new)):
         engine.step()                       # backpressure
 engine.drain()
-print(metrics.snapshot())                   # the BENCH_serve payload
+print(metrics.snapshot())
 ```
 
 Or from the launcher:

@@ -120,7 +120,7 @@ def cast(a: Tracer, dtype: str) -> Tracer:
 # --------------------------------------------------------------------------
 # serving-kernel graph builders — the production shapes expressed as
 # TensorIR so the whole pipeline (schedules, DSE, backends) applies to
-# them instead of only to hand-written pallas (ROADMAP open item #1)
+# them as to any other graph
 # --------------------------------------------------------------------------
 
 
@@ -133,7 +133,7 @@ def flash_attention_graph(sq: int, sk: int, d: int,
     0 where attendable and -1e30 where masked (causal/window/valid
     masking is data, so one graph covers every masking policy).
 
-    The online-softmax statistics of the hand kernel appear here as
+    The online-softmax statistics of a flash kernel appear here as
     carried ``reduce`` ops; tiling their reduction axis threads the
     running max/sum through the carry (see ``lowering.lower_reduce``).
     """

@@ -1,3 +1,6 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Hand-written Mosaic kernels that the served models run, each beside the
+# jnp form it is checked against: decode_attention.py (the KV-cache decode
+# step) and ssd_state_step.py (the Mamba2 state step). models/ picks
+# between a kernel and its jnp form (RunConfig.backend). ref.py holds the
+# plain jnp oracles the tests compare the model paths with. Add a kernel
+# here only for a path a served model runs, with its jnp form beside it.

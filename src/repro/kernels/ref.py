@@ -1,9 +1,12 @@
-"""Pure-jnp oracles for every kernel in this package.
+"""Plain jnp oracles for the compiler's kernels and the models' paths.
 
-Each pallas kernel in ``kernels/`` is validated against the function of
-the same name here (tests sweep shapes/dtypes and assert allclose) — the
-same discipline the paper applies by checking "accurate output matrices"
-from the generated RTL.
+Tests sweep shapes and dtypes and assert allclose against these:
+``gemm_ref`` checks the generated GEMM (``core.pipeline.compile_gemm``),
+``attention_ref`` the models' prefill attention
+(``models.layers.attention_core``), ``ssd_ref`` the Mamba2 prefill scan
+(``models.recurrent._ssd_with_state``) — the same discipline the paper
+applies by checking "accurate output matrices" from the generated RTL.
+The served decode kernels carry their own jnp forms beside them.
 """
 
 from __future__ import annotations

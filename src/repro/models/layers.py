@@ -124,7 +124,7 @@ def _mask(qpos: jax.Array, kpos: jax.Array, kv_len: Optional[jax.Array],
 
 
 def attention_math(q: jax.Array, k: jax.Array, v: jax.Array,
-                   mask: jax.Array, backend: str = "xla",
+                   mask: jax.Array,
                    scale: Optional[float] = None) -> jax.Array:
     """q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd); mask: (B, Sq, Sk) or
     broadcastable.  GQA via head grouping (no KV materialised repeat)."""

@@ -3,8 +3,8 @@
 TPU adaptation notes:
   * training-time RG-LRU uses ``jax.lax.associative_scan`` (log-depth,
     VPU-friendly) instead of a sequential loop;
-  * training-time SSD uses the chunked matmul decomposition
-    (``kernels.ssd_scan``) so the MXU does the work — the paper's
+  * training-time and prefill SSD use the chunked matmul decomposition
+    in jnp (``_ssd_with_state``) so the MXU does the work — the paper's
     "GEMM-ification of tensor ops" future-work item;
   * decode is a single recurrence step on cached state (constant memory —
     these are the archs that make the 500k-context cell feasible); the
@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import current_mesh, shard
-from repro.kernels import ops as kops
 from repro.kernels.ssd_state_step import ssd_state_step, ssd_state_step_ref
 from repro.models.layers import Maker, Params, rmsnorm
 
@@ -203,7 +202,7 @@ def apply_ssd(p: Params, x: jax.Array, cfg,
             h0 = jax.lax.dynamic_index_in_dim(states, at, 0, False)
             h0 = h0.reshape(B, N, H, P).transpose(0, 2, 3, 1)
         y, h_fin = _ssd_with_state(xs, dt, A, Bmat, Cmat, p["D"],
-                                   h0, s.chunk, backend)
+                                   h0, s.chunk)
         y = y.reshape(B, S, di)
         if cache is not None:
             h_fin = h_fin.transpose(0, 3, 1, 2).reshape(B, N, H * P)
@@ -221,7 +220,7 @@ def apply_ssd(p: Params, x: jax.Array, cfg,
     return x + shard(out, "batch", None, None), new_cache
 
 
-def _ssd_with_state(xs, dt, A, Bmat, Cmat, D, h0, chunk, backend):
+def _ssd_with_state(xs, dt, A, Bmat, Cmat, D, h0, chunk):
     """Batched chunked SSD that threads an initial/final state.
     xs: (B,S,H,P), dt: (B,S,H), Bmat/Cmat: (B,S,N)."""
     B_, S, H, P = xs.shape

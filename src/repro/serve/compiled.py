@@ -8,9 +8,8 @@ config, every raisable forward-pass block is compiled with
 legality-driven fallbacks down to the nested schedule) and validated
 against the traced reference on real inputs.  Blocks that do not raise,
 do not lower, or do not validate fall back to plain jit — explicitly,
-with the reason recorded, so a ``BENCH_serve.json`` entry always states
-exactly which blocks of the serving model ran through the compiler and
-which were XLA fallbacks.
+with the reason recorded, so the plan states exactly which blocks of the
+serving model compile through the stack and which stay XLA.
 """
 
 from __future__ import annotations

@@ -24,10 +24,11 @@ decode step, so an admission holds the decoding slots back by one piece
 a step however long its prompt is; a step in which no slot decodes
 prefills whole prompts, as with n = 0.
 
-With a mesh (``mesh``, else the model's own layout, ``Model.mesh``),
-parameters are expected already placed on it (see ``Model.init``), the
-stacked caches are allocated on it under the sharding rules, and every
-jit'd piece traces under those rules.  ``param_bytes_per_chip`` and
+The engine serves on the model's layout, ``Model.mesh``, which the
+run's ``model_parallel`` sets.  With a mesh, parameters are expected
+already placed on it (see ``Model.init``), the stacked caches are
+allocated on it under the sharding rules, and every jit'd piece traces
+under those rules.  ``param_bytes_per_chip`` and
 ``cache_bytes_per_chip`` count what one device holds of each.
 
 Per-slot sampling keys are derived by ``fold_in(base_key, rid)`` so the
@@ -62,11 +63,6 @@ from repro.distributed.sharding import (axis_rules, per_device_bytes,
 from repro.models.model import Model, mask_padded_vocab
 from repro.serve.metrics import ServeMetrics
 
-# prefill / decode step costs for deterministic VirtualClock runs (time
-# units; WallClock.advance ignores them)
-VIRTUAL_STEP_COST = 1.0
-VIRTUAL_PREFILL_COST = 1.0
-
 
 @dataclasses.dataclass
 class Request:
@@ -100,13 +96,16 @@ class ContinuousEngine:
                         test convenience; bypasses the queue limit).
       ``results``       rid -> generated ids (np.int32) of finished
                         requests.
+
+    It serves on ``model.mesh``.  ``metrics``, where given, receives the
+    lifecycle hooks (``on_submit``, ``on_reject``, ``on_admit``,
+    ``on_token``, ``on_step``, ``on_finish``) on its own clock.
     """
 
     def __init__(self, model: Model, params, slots: int = 4,
                  max_len: int = 256, temperature: float = 0.0,
                  seed: int = 0, queue_limit: Optional[int] = None,
-                 metrics: Optional[ServeMetrics] = None,
-                 plan=None, mesh=None):
+                 metrics: Optional[ServeMetrics] = None):
         self.model = model
         self.params = params
         self.slots = int(slots)
@@ -114,8 +113,7 @@ class ContinuousEngine:
         self.temperature = float(temperature)
         self.queue_limit = queue_limit
         self.metrics = metrics
-        self.plan = plan                       # ServeCompilePlan or None
-        self.mesh = model.mesh if mesh is None else mesh
+        self.mesh = model.mesh
         self.prefill_chunk = model.run.prefill_chunk
         self.base_key = jax.random.PRNGKey(seed)
 
@@ -312,8 +310,6 @@ class ContinuousEngine:
                 tok0, f.cache = self._extend_one(self.params, f.cache,
                                                  piece, f.sub)
         f.done += n
-        if self.metrics:
-            self.metrics.clock.advance(VIRTUAL_PREFILL_COST)
         return tok0
 
     def _finish(self, s: int) -> None:
@@ -347,8 +343,6 @@ class ContinuousEngine:
             self._tok, self._stacked, self._keys = self._decode_all(
                 self.params, self._stacked, self._tok, jnp.asarray(active),
                 self._keys)
-        if self.metrics:
-            self.metrics.clock.advance(VIRTUAL_STEP_COST)
         with TraceAnnotation("serve.sync"):
             toks = np.asarray(self._tok[:, 0])
         emitted = 0

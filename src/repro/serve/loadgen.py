@@ -5,9 +5,8 @@ arrival times from a Poisson / bursty (Markov-modulated Poisson) /
 uniform process, prompt and output lengths from configurable
 distributions, token ids from the same seeded generator.  The whole
 stream is a pure function of :class:`LoadConfig` — identical config
-(including ``seed``) always yields the identical stream, so every
-``BENCH_serve.json`` entry names the workload it was measured under and
-any run can be replayed bit-for-bit.
+(including ``seed``) always yields the identical stream, so any run can
+be replayed bit-for-bit.
 """
 
 from __future__ import annotations

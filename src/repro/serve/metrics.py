@@ -6,11 +6,11 @@ request count — the structure every serving system uses for tail
 latency.  ``ServeMetrics`` ties the histograms to the request lifecycle
 (arrival -> admit -> first token -> per-token -> finish), tracks queue
 depth and slot occupancy per engine step, and snapshots everything into
-the JSON dict ``BENCH_serve.json`` entries embed.
+one JSON-able dict.
 
 Time comes from a :class:`Clock`: ``WallClock`` for real measurements,
-``VirtualClock`` for deterministic transcripts (docs, CI smoke) where
-each engine step advances time by a fixed cost instead of wall time.
+``VirtualClock`` for tests, where time moves only when the caller
+advances it.
 """
 
 from __future__ import annotations
@@ -33,7 +33,7 @@ class Clock:
         raise NotImplementedError
 
     def advance(self, dt: float) -> None:
-        """Engine hooks call this per step; real clocks ignore it."""
+        """Move a virtual clock on; real clocks ignore it."""
 
     kind = "abstract"
 
@@ -227,7 +227,7 @@ class ServeMetrics:
         return self._occupancy / (self._steps * self.slots)
 
     def snapshot(self) -> Dict:
-        """JSON-able summary — the per-run payload of BENCH_serve.json."""
+        """JSON-able summary of the run."""
         dur = self.duration
         toks = self.decode_tokens
         return {

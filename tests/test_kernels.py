@@ -1,4 +1,5 @@
-"""Per-kernel allclose sweeps against the pure-jnp oracles in ref.py."""
+"""Kernels and the models' paths, in allclose sweeps against the
+pure-jnp oracles in ``ref.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -6,29 +7,63 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.ssd_scan import ssd_chunked, ssd_scan
+from repro.core.pipeline import compile_gemm
+from repro.kernels import ref
+from repro.models.layers import _DIRECT_LIMIT, attention_core
+from repro.models.recurrent import _ssd_with_state
 
 
 # ---- GEMM (stagecc-generated) ---------------------------------------------
+
+
+def _pick_tile(dim: int, pref: int = 128) -> int:
+    """The largest tile of at most ``pref`` that divides ``dim``."""
+    t = min(pref, dim)
+    while dim % t:
+        t -= 1
+    return t
 
 
 @pytest.mark.parametrize("shape", [(128, 128, 128), (256, 128, 64),
                                    (64, 192, 256), (96, 96, 96)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gemm_shapes_dtypes(shape, dtype):
+    """The generated GEMM, as the kernel cell compiles it
+    (``compile_gemm(...).run_pallas``), against the oracle."""
     m, n, k = shape
     rng = np.random.default_rng(sum(shape))
     a = jnp.asarray(rng.standard_normal((m, k)), dtype)
     b = jnp.asarray(rng.standard_normal((k, n)), dtype)
-    got = np.asarray(ops.matmul(a, b, backend="pallas")).astype(np.float32)
+    ck = compile_gemm(m, n, k, schedule="tpu_mxu_kgrid",
+                      dtype=jnp.dtype(dtype).name,
+                      tile={"m": _pick_tile(m), "n": _pick_tile(n),
+                            "k": _pick_tile(k)},
+                      want_jax=False)
+    assert ck.run_pallas is not None, ck.pallas_error
+    got = np.asarray(ck.run_pallas(a, b)).astype(np.float32)
     want = np.asarray(ref.gemm_ref(a, b))
     tol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol * 10)
 
 
-# ---- flash attention ---------------------------------------------------------
+# ---- prefill attention (models.layers.attention_core) ------------------------
+
+
+def _attention(q, k, v, causal=True, window=None, **blocks):
+    """``attention_core`` on head-batched (H, S, d) operands, laid out
+    as the model calls it: (1, S, H, d), query i at position
+    i + Sk - Sq."""
+    sq, sk = q.shape[1], k.shape[1]
+    qpos = jnp.arange(sq)[None] + (sk - sq)
+    kpos = jnp.arange(sk)[None]
+    out = attention_core(*(x.transpose(1, 0, 2)[None] for x in (q, k, v)),
+                         qpos, kpos, None, causal, window, **blocks)
+    return out[0].transpose(1, 0, 2)
+
+
+def _attention_ref(q, k, v, causal=True, window=None):
+    return jax.vmap(lambda qq, kk, vv: ref.attention_ref(
+        qq, kk, vv, causal=causal, window=window))(q, k, v)
 
 
 @pytest.mark.parametrize("sq,sk,d,causal,window", [
@@ -43,26 +78,34 @@ def test_flash_attention_vs_ref(sq, sk, d, causal, window):
     q = jnp.asarray(rng.standard_normal((3, sq, d)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((3, sk, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((3, sk, d)), jnp.float32)
-    got = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=64, block_k=64)
-    want = jax.vmap(lambda qq, kk, vv: ref.attention_ref(
-        qq, kk, vv, causal=causal, window=window))(q, k, v)
+    got = _attention(q, k, v, causal, window)
+    want = _attention_ref(q, k, v, causal, window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
 @settings(max_examples=8, deadline=None)
-@given(bq=st.sampled_from([32, 64, 128]), bk=st.sampled_from([32, 64, 128]))
+@given(bq=st.sampled_from([256, 512, 1024]),
+       bk=st.sampled_from([256, 512, 1024]))
 def test_flash_attention_block_invariance(bq, bk):
-    """Output must not depend on the BlockSpec tiling choice."""
+    """At a size past ``_DIRECT_LIMIT`` attention runs blockwise (an
+    online softmax over KV blocks in a scan over Q blocks); its output
+    must not depend on the block sizes, and matches the oracle."""
+    s, d = 2048, 32
+    assert s * s > _DIRECT_LIMIT
     rng = np.random.default_rng(42)
-    q = jnp.asarray(rng.standard_normal((2, 128, 32)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((2, 128, 32)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((2, 128, 32)), jnp.float32)
-    a = flash_attention(q, k, v, block_q=bq, block_k=bk)
-    b = flash_attention(q, k, v, block_q=128, block_k=128)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, s, d)), jnp.float32)
+               for _ in range(3))
+    blockwise = jax.jit(lambda q, k, v: _attention(
+        q, k, v, block_q=bq, block_k=bk))
+    assert "scan" in str(jax.make_jaxpr(blockwise)(q, k, v))
+    a = blockwise(q, k, v)
+    b = _attention(q, k, v, block_q=s, block_k=s)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                atol=2e-5)
+    np.testing.assert_allclose(np.asarray(a),
+                               np.asarray(_attention_ref(q, k, v)),
+                               rtol=1e-4, atol=1e-4)
 
 
 def test_flash_attention_bf16():
@@ -70,13 +113,12 @@ def test_flash_attention_bf16():
     q = jnp.asarray(rng.standard_normal((2, 128, 64)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((2, 128, 64)), jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((2, 128, 64)), jnp.bfloat16)
-    got = np.asarray(flash_attention(q, k, v)).astype(np.float32)
-    want = np.asarray(jax.vmap(lambda a, b, c: ref.attention_ref(a, b, c))(
-        q, k, v)).astype(np.float32)
+    got = np.asarray(_attention(q, k, v)).astype(np.float32)
+    want = np.asarray(_attention_ref(q, k, v)).astype(np.float32)
     np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2)
 
 
-# ---- SSD ---------------------------------------------------------------------
+# ---- prefill SSD (models.recurrent._ssd_with_state) --------------------------
 
 
 def _ssd_inputs(S, H, P, N, seed=0):
@@ -89,12 +131,27 @@ def _ssd_inputs(S, H, P, N, seed=0):
             jnp.asarray(rng.standard_normal(H), jnp.float32))
 
 
+def _ssd(x, dt, A, B, C, D, chunk, h0=None):
+    """``_ssd_with_state`` on one sequence: (y, final state)."""
+    y, h = _ssd_with_state(x[None], dt[None], A, B[None], C[None], D,
+                           None if h0 is None else h0[None], chunk)
+    return y[0], h[0]
+
+
 @pytest.mark.parametrize("chunk", [16, 32, 64])
 def test_ssd_pallas_vs_naive(chunk):
+    """The Mamba2 prefill scan against the sequential oracle; a prompt
+    prefilled in two pieces, the second from the first's final state,
+    gives the same output."""
     x, dt, A, B, C, D = _ssd_inputs(128, 4, 16, 8)
     want = np.asarray(ref.ssd_ref(x, dt, A, B, C, D))
-    got = np.asarray(ssd_scan(x, dt, A, B, C, D, chunk=chunk))
-    np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-4)
+    got, _ = _ssd(x, dt, A, B, C, D, chunk)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-3, atol=1e-4)
+    head, h = _ssd(*(a[:48] for a in (x, dt)), A, B[:48], C[:48], D, chunk)
+    tail, _ = _ssd(*(a[48:] for a in (x, dt)), A, B[48:], C[48:], D, chunk,
+                   h0=h)
+    np.testing.assert_allclose(np.concatenate([head, tail]), want,
+                               rtol=1e-3, atol=1e-4)
 
 
 @settings(max_examples=6, deadline=None)
@@ -103,16 +160,19 @@ def test_ssd_pallas_vs_naive(chunk):
 def test_ssd_chunked_hypothesis(S, H, P, N):
     x, dt, A, B, C, D = _ssd_inputs(S, H, P, N, seed=S + H + P)
     want = np.asarray(ref.ssd_ref(x, dt, A, B, C, D))
-    got = np.asarray(ssd_chunked(x, dt, A, B, C, D, chunk=16))
-    np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-4)
+    got, _ = _ssd(x, dt, A, B, C, D, chunk=16)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-3, atol=1e-4)
 
 
 def test_ssd_chunk_invariance():
     """Chunk size is a schedule choice — results must be identical."""
     x, dt, A, B, C, D = _ssd_inputs(128, 2, 8, 4, seed=11)
-    a = np.asarray(ssd_chunked(x, dt, A, B, C, D, chunk=16))
-    b = np.asarray(ssd_chunked(x, dt, A, B, C, D, chunk=64))
-    np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    a, ha = _ssd(x, dt, A, B, C, D, chunk=16)
+    b, hb = _ssd(x, dt, A, B, C, D, chunk=64)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(ha), np.asarray(hb), rtol=1e-4,
+                               atol=1e-5)
 
 
 # ---- RG-LRU oracle sanity -----------------------------------------------------

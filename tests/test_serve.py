@@ -107,3 +107,18 @@ def test_throughput_counts_only_real_tokens():
     full = throughput_stats(eng, prompts, 6)
     assert full["tokens"] == 12
     assert 0 < stats["tokens"] < full["tokens"]
+
+
+@pytest.mark.parametrize("arch", ["qwen2_7b", "mamba2_130m"])
+def test_plan_blocks_compiles_and_validates(arch):
+    """The per-block compile plan: at least one forward-pass block of the
+    serving model compiles through the stack and validates against the
+    traced reference; every block is either compiled or a fallback with
+    its reason."""
+    from repro.serve.compiled import plan_blocks
+
+    plan = plan_blocks(arch)
+    assert any(c.reason.startswith("validated") for c in plan.compiled)
+    assert len(plan.compiled) + len(plan.fallbacks) == len(plan.choices)
+    assert all(c.reason for c in plan.fallbacks)
+    assert plan.describe().count("\n") == len(plan.choices)
